@@ -156,14 +156,15 @@ def sage_layer_apply(params: dict, eps: jnp.ndarray, adj: jnp.ndarray,
                               [, Σ_{j∈out(i)} f2_out(eps_j)]) ) )
 
     use_pallas=True routes the transform+aggregate through the fused
-    repro.kernels.graph_aggregate kernel (beyond-paper optimization —
-    interpret-mode on CPU, real VMEM fusion on TPU).
+    repro.kernels.graph_aggregate kernel (beyond-paper optimization):
+    interpreted off-TPU; it compiles for a v5e at B=32, N=64, D=F=192
+    (tests/test_tpu_compile.py). Its speed is not measured.
     """
     _TRACE_COUNTS["dense"] += 1
     if use_pallas:
+        from repro.kernels import interpret_mode
         from repro.kernels.graph_aggregate.ops import graph_aggregate
-        import jax as _jax
-        interp = _jax.default_backend() == "cpu"
+        interp = interpret_mode()
         mean = aggregator == "mean"
         agg_in = graph_aggregate(adj, eps, params["f2_in"]["w"],
                                  act="relu", mean=mean, interpret=interp)
@@ -301,17 +302,19 @@ def sage_layer_apply_sparse_q(params: dict, eps: jnp.ndarray,
                               edge_src: jnp.ndarray, edge_dst: jnp.ndarray,
                               edge_mask: jnp.ndarray, node_mask: jnp.ndarray,
                               *, aggregator: str = "mean",
-                              directed: bool = True,
-                              interpret: bool = False) -> jnp.ndarray:
+                              directed: bool = True) -> jnp.ndarray:
     """`sage_layer_apply_sparse` with the transform+aggregate fused into
     the `repro.kernels.segment_aggregate` Pallas kernel (inference-only —
     the kernel has no VJP; the trainer stays on the jnp twin). The f2
     weights may be int8 `QuantizedLeaf`s (dequantized in-VMEM, DESIGN.md
-    §14) or plain f32; f3 is dequantized outside the kernel either way."""
+    §14) or plain f32; f3 is dequantized outside the kernel either way.
+    The kernel is interpreted off-TPU (`kernels.interpret_mode`)."""
+    from repro.kernels import interpret_mode
     from repro.kernels.segment_aggregate.ops import segment_aggregate
     from repro.quant.scale import leaf_f32
     _TRACE_COUNTS["sparse"] += 1
     mean = aggregator == "mean"
+    interpret = interpret_mode()
 
     def fused(leaf, gather, scatter):
         w, scale = _f2_qs(leaf)
@@ -335,20 +338,14 @@ def sage_layer_apply_sparse_q(params: dict, eps: jnp.ndarray,
 def sage_apply_sparse_q(params: dict, eps: jnp.ndarray,
                         edge_src: jnp.ndarray, edge_dst: jnp.ndarray,
                         edge_mask: jnp.ndarray, node_mask: jnp.ndarray, *,
-                        aggregator: str = "mean", directed: bool = True,
-                        interpret: bool | None = None) -> jnp.ndarray:
-    """Kernel-backed twin of `sage_apply_sparse` (f32 or int8 params).
-    `interpret` defaults to CPU-backend detection, like the dense
-    `use_pallas` path."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-
+                        aggregator: str = "mean",
+                        directed: bool = True) -> jnp.ndarray:
+    """Kernel-backed twin of `sage_apply_sparse` (f32 or int8 params)."""
     def layer_fn(layer, h):
         return sage_layer_apply_sparse_q(layer, h, edge_src, edge_dst,
                                          edge_mask, node_mask,
                                          aggregator=aggregator,
-                                         directed=directed,
-                                         interpret=interpret)
+                                         directed=directed)
     return _apply_stack(params, eps, layer_fn)
 
 

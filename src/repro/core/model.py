@@ -55,7 +55,9 @@ class CostModelConfig:
     gat_heads: int = 2
     dropout: float = 0.1
     max_nodes: int = 64
-    use_pallas_aggregate: bool = False   # fused Pallas graph_aggregate path
+    # fused Pallas aggregation: kernels/graph_aggregate on the dense
+    # layout, kernels/segment_aggregate (inference-only) on the sparse one
+    use_pallas_aggregate: bool = False
     # batched-graph representation the data path should produce for this
     # model: 'dense' ([B,N,N] padded adjacency, MXU matmul aggregation) or
     # 'sparse' (packed SparseGraphBatch + segment_sum). `cost_model_apply`
@@ -71,7 +73,7 @@ class CostModelConfig:
     # Numeric format of the parameter tree `cost_model_apply` receives:
     # 'f32' (plain arrays) or 'int8' (repro.quant — weights are
     # `QuantizedLeaf`s, dequantized inside jit; with use_pallas_aggregate
-    # on the sparse layouts the GNN f2 weights instead stay int8 all the
+    # on the sparse layout the GNN f2 weights instead stay int8 all the
     # way into the fused segment_aggregate kernel). Inference-only: the
     # trainer always trains f32 and `repro.quant.quantize_params`
     # produces the int8 tree afterwards (DESIGN.md §14).
@@ -88,7 +90,13 @@ class CostModelConfig:
             raise ValueError(
                 f"use_pallas_aggregate supports gnn='graphsage' only, got "
                 f"gnn={self.gnn!r} (dense layout: kernels/graph_aggregate; "
-                "sparse/segmented: kernels/segment_aggregate)")
+                "sparse: kernels/segment_aggregate)")
+        if self.use_pallas_aggregate and self.adjacency == "segmented":
+            raise ValueError(
+                "use_pallas_aggregate does not support adjacency="
+                "'segmented': a whole-program segment pack has no node "
+                "bound, and kernels/segment_aggregate keeps the whole pack "
+                "in VMEM (ops.VMEM_LIMIT_BYTES)")
 
     def to_dict(self) -> dict:
         return asdict(self)
@@ -147,7 +155,7 @@ def cost_model_apply(params: dict, cfg: CostModelConfig, batch,
     one parameter tree and agree numerically (DESIGN.md §4)."""
     if cfg.precision == "int8":
         from repro.quant.scale import dequantize_tree
-        # sparse/segmented + Pallas: the GNN tree stays quantized — its f2
+        # sparse + Pallas: the GNN tree stays quantized — its f2
         # weights feed the segment_aggregate kernel as int8 and are
         # dequantized in-VMEM; everything else decodes here, inside jit
         keep_gnn = (cfg.use_pallas_aggregate and "gnn" in params

@@ -30,13 +30,15 @@ scratch accumulators persist across steps. BlockSpecs:
   scatter [1, block_e]  index (0, e)
   emask   [1, block_e]  index (0, e)
   out     [M, F]        index (0, 0)    (revisited every step)
-Scratch: msg [M, F] f32 + deg [M, 1] f32 in VMEM. Per-step VMEM ≈
-M·D + D·F + 2·M·F + 2·block_e·M floats — M=512, D=F=256, block_e=256
-→ ~1.2 MB, far under VMEM. Bucketed capacities (data/batching.py) are
-pow2, so M/D/F/E arrive tiling-friendly; `ops.segment_aggregate` pads
-the stragglers. `block_e` candidates for the tile-size autotuner come
-from `ops.block_candidates` (the `graph_aggregate.block_candidates`
-idiom).
+Scratch: msg [M, F] f32 + deg [M, 1] f32 in VMEM. VMEM grows with M:
+`ops.vmem_bytes` reckons M·(D + 2·F + 1 + 2·block_e) floats, and
+`ops.segment_aggregate` refuses a pack over `ops.VMEM_LIMIT_BYTES`
+(M=2048, D=F=256 fits; M=4096 does not — the v5e compiler refuses it
+too). Bucketed capacities (data/batching.py) are pow2, so M/D/F/E arrive
+tiling-friendly; `ops.segment_aggregate` pads the stragglers. `block_e`
+must be a multiple of 128 (the edge blocks are lane-major); candidates
+for the tile-size autotuner come from `ops.block_candidates` (the
+`graph_aggregate.block_candidates` idiom).
 """
 from __future__ import annotations
 

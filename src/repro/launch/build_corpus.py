@@ -25,9 +25,10 @@ Train from the result:
 
 This module must stay importable without jax: workers fork/spawn from it,
 synthetic generation + the oracle are pure numpy, and only ``--import-archs``
-tasks load jax (lazily, inside the worker). The default ``--mp-context
-auto`` forks when that is safe (jax not yet loaded in the parent) and
-spawns otherwise.
+tasks load jax (lazily, inside the worker, pinned to the host CPU so no
+worker opens the accelerator). The default ``--mp-context auto`` forks
+when that is safe (jax not yet loaded in the parent) and spawns
+otherwise.
 """
 from __future__ import annotations
 
@@ -65,6 +66,13 @@ def _build_program(task: tuple, seed: int):
         from repro.core.hlo_import import import_arch_program   # loads jax
         return import_arch_program(task[1])
     raise ValueError(f"unknown task {task!r}")
+
+
+def _pin_worker_to_cpu() -> None:
+    """Pool initializer: a worker that imports jax (``--import-archs``
+    tasks) traces on the host CPU and never opens the accelerator, which
+    belongs to one process at a time."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def _run_task(args: tuple) -> dict:
@@ -173,7 +181,8 @@ def build_corpus(out_dir: str, *, kinds=("tile", "fusion"), programs: int = 48,
             _merge(results, writers, len(tasks), log)
         else:
             ctx = multiprocessing.get_context(_pick_context(mp_context))
-            with ctx.Pool(processes=workers) as pool:
+            with ctx.Pool(processes=workers,
+                          initializer=_pin_worker_to_cpu) as pool:
                 # imap (not imap_unordered): merge order == task order, so
                 # the store is identical no matter how many workers ran
                 _merge(pool.imap(_run_task, job_args), writers,

@@ -50,6 +50,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     from repro.core.model import CostModelConfig
     from repro.core.simulator import TPUSimulator
     from repro.data.store import StreamingCorpus, load_manifest, write_corpus

@@ -7,7 +7,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before first init.
 from __future__ import annotations
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,7 +15,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2×16×16 = 512 chips ("pod","data","model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=None, axes=("data", "model")):
@@ -23,7 +24,8 @@ def make_host_mesh(shape=None, axes=("data", "model")):
     n = jax.device_count()
     if shape is None:
         shape = (n, 1)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def activation_mapping(mesh) -> dict:

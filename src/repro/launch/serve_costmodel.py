@@ -34,8 +34,8 @@ Flags:
   --cache-capacity N  LRU prediction-cache entries            (default 65536)
   --node-budget N     sparse pack budget / coalescer flush    (default 8*max_nodes)
   --chunk N           dense chunk width                       (default 128)
-  --hidden-dim N      model width (untrained params; serving  (default 48)
-                      throughput does not depend on training)
+  --hidden-dim N      model width (untrained params; serving  (default: the
+                      throughput does not depend on training)  model's, 192)
   --precision P       f32 | int8 serving weights (int8 runs   (default f32)
                       `repro.quant.quantize_params` on the
                       init params, calibrated on the stream)
@@ -63,39 +63,45 @@ def _host_port(spec: str) -> tuple[str, int]:
     return host, int(port)
 
 
-def _maybe_quantize(params, cfg, replay, args):
-    """--precision int8: quantize the weights per-channel, calibrating on
-    a slice of the replay stream; returns the (params, cfg) to serve."""
-    if args.precision != "int8":
-        return params, cfg
-    from repro.quant import quantize_params
-
-    calib = [g for req in replay.requests[:4] for g in req]
-    qm = quantize_params(params, cfg, calib_graphs=calib,
-                         normalizer=replay.normalizer)
-    return qm.params, qm.serving_config(cfg)
-
-
-def _serve(args) -> int:
-    """--listen: stand up the model + socket server, block until ^C."""
+def _build_model(args):
+    """The replay stream and the untrained model to serve it with, at the
+    model's own widths. --precision int8 quantizes the weights
+    per-channel, calibrating on a slice of the stream. Returns
+    (replay, params, cfg)."""
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     import jax
 
-    from repro.core.evaluate import make_predict_fn
     from repro.core.model import CostModelConfig, cost_model_init
-    from repro.serving import CostModelService
     from repro.serving.replay import build_tile_replay
-    from repro.serving.server import CostModelServer
 
     replay = build_tile_replay(args.programs, max_configs=args.max_configs,
                                rounds=args.rounds, subset=args.subset,
                                seed=args.seed)
     max_nodes = max(g.num_nodes for r in replay.requests for g in r)
     cfg = CostModelConfig(gnn="graphsage", reduction="column_wise",
-                          hidden_dim=args.hidden_dim, opcode_embed_dim=16,
+                          hidden_dim=(args.hidden_dim
+                                      or CostModelConfig.hidden_dim),
                           dropout=0.0, max_nodes=max_nodes,
                           adjacency=args.adjacency)
     params = cost_model_init(jax.random.key(args.seed), cfg)
-    params, cfg = _maybe_quantize(params, cfg, replay, args)
+    if args.precision != "int8":
+        return replay, params, cfg
+    from repro.quant import quantize_params
+
+    calib = [g for req in replay.requests[:4] for g in req]
+    qm = quantize_params(params, cfg, calib_graphs=calib,
+                         normalizer=replay.normalizer)
+    return replay, qm.params, qm.serving_config(cfg)
+
+
+def _serve(args) -> int:
+    """--listen: stand up the model + socket server, block until ^C."""
+    from repro.core.evaluate import make_predict_fn
+    from repro.serving import CostModelService
+    from repro.serving.server import CostModelServer
+
+    replay, params, cfg = _build_model(args)
     service = CostModelService(params, cfg, replay.normalizer,
                                cache_capacity=args.cache_capacity,
                                node_budget=args.node_budget,
@@ -164,7 +170,7 @@ def main() -> int:
     ap.add_argument("--cache-capacity", type=int, default=65536)
     ap.add_argument("--node-budget", type=int, default=None)
     ap.add_argument("--chunk", type=int, default=128)
-    ap.add_argument("--hidden-dim", type=int, default=48)
+    ap.add_argument("--hidden-dim", type=int, default=None)
     ap.add_argument("--precision", choices=("f32", "int8"), default="f32")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--compare-direct", action="store_true")
@@ -181,23 +187,12 @@ def main() -> int:
     if args.connect:
         return _connect(args)
 
-    import jax
-
     from repro.core.evaluate import make_predict_fn, predict_kernels
-    from repro.core.model import CostModelConfig, cost_model_init
     from repro.serving import CostModelService
-    from repro.serving.replay import build_tile_replay, run_replay
+    from repro.serving.replay import run_replay
 
-    replay = build_tile_replay(args.programs, max_configs=args.max_configs,
-                               rounds=args.rounds, subset=args.subset,
-                               seed=args.seed)
-    max_nodes = max(g.num_nodes for r in replay.requests for g in r)
-    cfg = CostModelConfig(gnn="graphsage", reduction="column_wise",
-                          hidden_dim=args.hidden_dim, opcode_embed_dim=16,
-                          dropout=0.0, max_nodes=max_nodes,
-                          adjacency=args.adjacency)
-    params = cost_model_init(jax.random.key(args.seed), cfg)
-    params, cfg = _maybe_quantize(params, cfg, replay, args)
+    replay, params, cfg = _build_model(args)
+    max_nodes = cfg.max_nodes
     predict_fn = make_predict_fn(cfg)
     print(f"replay: {replay.num_kernels} kernels, "
           f"{len(replay.requests)} requests, {replay.num_queries} queries "

@@ -103,9 +103,10 @@ def train_cost_model(args) -> None:
         else:
             ds = build_fusion_dataset(programs, sim, configs_per_program=12)
         recs = filter_by_programs(ds.records, split["train"])
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     mc = CostModelConfig(gnn=args.gnn, reduction=args.reduction,
-                         hidden_dim=args.hidden, opcode_embed_dim=32,
-                         max_nodes=args.max_nodes)
+                         hidden_dim=args.hidden, max_nodes=args.max_nodes)
     if want_kind == "tile":
         from repro.data.tile_dataset import fit_tile_normalizer
         norm = fit_tile_normalizer(recs)
@@ -141,6 +142,8 @@ def train_cost_model(args) -> None:
 
 
 def train_lm(args) -> None:
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     import jax
     from repro.models import lm, registry
     from repro.models.config import ShapeSpec
@@ -163,6 +166,8 @@ def train_lm(args) -> None:
 
 
 def main() -> None:
+    from repro.core.model import CostModelConfig
+    model_defaults = CostModelConfig()
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
 
@@ -197,8 +202,9 @@ def main() -> None:
                     choices=["random", "manual"])
     cm.add_argument("--gnn", default="graphsage")
     cm.add_argument("--reduction", default="transformer")
-    cm.add_argument("--hidden", type=int, default=64)
-    cm.add_argument("--max-nodes", type=int, default=48)
+    cm.add_argument("--hidden", type=int, default=model_defaults.hidden_dim)
+    cm.add_argument("--max-nodes", type=int,
+                    default=model_defaults.max_nodes)
     cm.add_argument("--lr", type=float, default=2e-3)
     cm.add_argument("--seed", type=int, default=0)
     cm.add_argument("--ckpt-dir", default="ckpts/cost_model")

@@ -197,14 +197,14 @@ def attn_apply_train(params: dict, cfg: ModelConfig, x: jnp.ndarray, *,
     positions = q_offset + jnp.arange(S)
     q, k, v = attn_qkv(params, cfg, x, positions)
     if cfg.use_pallas_attn:
+        from repro.kernels import interpret_mode
         from repro.kernels.flash_attention.ops import flash_attention
-        interp = jax.default_backend() == "cpu"
         out = flash_attention(q, k, v, causal=True, window=window,
                               q_offset=int(q_offset) if not hasattr(
                                   q_offset, "dtype") else 0,
                               block_q=min(128, S), block_k=min(cfg.block_kv,
                                                                S),
-                              interpret=interp)
+                              interpret=interpret_mode())
     else:
         out = chunked_attention(q, k, v, causal=True, window=window,
                                 q_offset=q_offset, block_kv=cfg.block_kv)

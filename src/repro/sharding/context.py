@@ -92,15 +92,6 @@ def constrain_batch_tree(tree, leading: int = 1):
 
 
 def shard_map_nocheck(f, mesh, in_specs, out_specs):
-    """`shard_map` with replication/VMA checking off, spelled compatibly:
-    the entry point moved from jax.experimental to jax, and the kwarg was
-    renamed check_rep → check_vma, on independent version boundaries."""
-    import inspect
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    kw = ("check_vma" if "check_vma" in inspect.signature(_sm).parameters
-          else "check_rep")
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **{kw: False})
+    """`jax.shard_map` with varying-manual-axes (VMA) checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -211,6 +211,19 @@ def test_build_corpus_workers_match_serial(tmp_path):
         assert m1[kind]["manifest_hash"] == m2[kind]["manifest_hash"]
 
 
+def test_build_corpus_workers_never_open_the_accelerator(monkeypatch):
+    """The pool initializer pins each worker to the host CPU before any
+    task can import jax, so workers never contend for the chip even when
+    the parent's environment names one."""
+    import multiprocessing
+    from repro.launch.build_corpus import _pin_worker_to_cpu
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    ctx = multiprocessing.get_context("spawn")
+    with ctx.Pool(processes=2, initializer=_pin_worker_to_cpu) as pool:
+        got = [pool.apply(os.getenv, ("JAX_PLATFORMS",)) for _ in range(4)]
+    assert got == ["cpu"] * 4
+
+
 # -------------------------------------------------------- streaming parity
 def test_tile_sampler_stream_parity(tile_records, tmp_path):
     d = str(tmp_path / "t")

@@ -28,10 +28,12 @@ def test_smoke_archs_lower_on_mesh():
     a 4×2 host mesh with the production partition rules."""
     out = _run("""
         import jax
+        from jax.sharding import AxisType
         from repro.models import registry
         from repro.models.config import ShapeSpec
         from repro.launch.lowering import lower_cell
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         shapes = [ShapeSpec("t", 64, 8, "train"),
                   ShapeSpec("p", 64, 8, "prefill"),
                   ShapeSpec("d", 64, 8, "decode"),
@@ -53,10 +55,12 @@ def test_multipod_mesh_smoke():
     """(pod, data, model) mesh lowers a train step; pod axis shards batch."""
     out = _run("""
         import jax
+        from jax.sharding import AxisType
         from repro.models import registry
         from repro.models.config import ShapeSpec
         from repro.launch.lowering import lower_cell
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(AxisType.Auto,) * 3)
         cfg = registry.get_smoke_config("yi-9b")
         cell = lower_cell("yi-9b", cfg, ShapeSpec("t", 64, 8, "train"),
                           mesh, "multipod")
@@ -142,10 +146,12 @@ def test_pipeline_parallel_matches_sequential():
     """GPipe pipeline over 4 stages == sequential layer application."""
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.training.pipeline import pipeline_apply, \
             pipeline_stage_split
 
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = jax.make_mesh((4,), ("stage",),
+                             axis_types=(AxisType.Auto,))
         L, D, M, mb = 8, 16, 6, 2
         key = jax.random.key(0)
         Ws = jax.random.normal(key, (L, D, D)) * 0.3

@@ -22,6 +22,7 @@ CURATED_MODULES = [
     "repro.data.segmentation",
     "repro.data.prefetch",
     "repro.data.store",
+    "repro.kernels.segment_aggregate.ops",
     "repro.autotuner.tile_autotuner",
     "repro.quant.scale",
     "repro.quant.quantize",

@@ -89,7 +89,7 @@ def test_graph_aggregate_matches_ref(case):
     x = RNG.normal(0, 1, (B, N, D)).astype(np.float32)
     w = RNG.normal(0, 1, (D, F)).astype(np.float32)
     out = graph_aggregate(jnp.asarray(adj), jnp.asarray(x), jnp.asarray(w),
-                          act=act, mean=mean, block_f=64, interpret=True)
+                          act=act, mean=mean, block_f=128, interpret=True)
     ref = graph_aggregate_ref(adj, x, w, act=act, mean=mean)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-4)
 
@@ -159,7 +159,7 @@ def test_segment_aggregate_matches_ref(case):
     out = segment_aggregate(jnp.asarray(x), jnp.asarray(w), jnp.asarray(s),
                             jnp.asarray(g), jnp.asarray(sc), jnp.asarray(em),
                             jnp.asarray(nm), act=act, mean=mean,
-                            block_e=64, interpret=True)
+                            block_e=128, interpret=True)
     ref = segment_aggregate_ref(x, w, s, g, sc, em, nm, act=act, mean=mean)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
 
@@ -181,9 +181,11 @@ def test_segment_aggregate_bitexact_on_integers(mean):
 def test_segment_aggregate_block_e_invariance():
     """Different edge-block widths must give identical results — the
     property the block_candidates autotuner hints rely on."""
-    args = [jnp.asarray(a) for a in _seg_inputs(24, 16, 32, 200, seed=3)]
+    args = [jnp.asarray(a) for a in _seg_inputs(24, 16, 32, 600, seed=3)]
+    blocks = block_candidates(600)
+    assert blocks == [128, 256, 512]
     outs = [segment_aggregate(*args, block_e=be, interpret=True)
-            for be in block_candidates(200) + [8]]
+            for be in blocks]
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                    rtol=1e-6, atol=1e-6)
@@ -206,7 +208,7 @@ def test_segment_aggregate_property(m, e, mean):
     x, w, s, g, sc, em, nm = _seg_inputs(m, 6, 10, e, seed=m * 41 + e)
     out = segment_aggregate(jnp.asarray(x), jnp.asarray(w), jnp.asarray(s),
                             jnp.asarray(g), jnp.asarray(sc), jnp.asarray(em),
-                            jnp.asarray(nm), mean=mean, block_e=32,
+                            jnp.asarray(nm), mean=mean, block_e=128,
                             interpret=True)
     ref = segment_aggregate_ref(x, w, s, g, sc, em, nm, mean=mean)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
@@ -235,3 +237,51 @@ def test_ssd_scan_first_chunk_state_is_zero():
     d = jnp.full((1, 3, 1), 0.5)
     hb, _ = ssd_scan(S, d, interpret=True)
     assert float(jnp.max(jnp.abs(hb[:, 0]))) == 0.0
+
+
+# ------------------------------------------------- TPU-legal block shapes
+def test_segment_aggregate_refuses_pack_over_vmem():
+    """A pack whose whole-node-axis blocks cannot fit VMEM fails with a
+    ValueError naming the bound, before any compiler sees it (the v5e
+    compiler refuses M=4096, D=F=256 with RESOURCE_EXHAUSTED)."""
+    from repro.kernels.segment_aggregate.ops import (VMEM_LIMIT_BYTES,
+                                                     vmem_bytes)
+    assert vmem_bytes(2048, 256, 256, 256) <= VMEM_LIMIT_BYTES
+    M, D, E = 4096, 256, 8192
+    x = jnp.zeros((M, D), jnp.float32)
+    w = jnp.zeros((D, D), jnp.float32)
+    idx = jnp.zeros((E,), jnp.int32)
+    with pytest.raises(ValueError, match="VMEM_LIMIT_BYTES"):
+        segment_aggregate(x, w, jnp.ones((1, D)), idx, idx,
+                          jnp.ones((E,)), jnp.ones((M,)), interpret=True)
+
+
+@pytest.mark.parametrize("block_e", [64, 8, 200])
+def test_segment_aggregate_refuses_unaligned_block(block_e):
+    args = [jnp.asarray(a) for a in _seg_inputs(16, 8, 16, 40, seed=1)]
+    with pytest.raises(ValueError, match="multiple of 128"):
+        segment_aggregate(*args, block_e=block_e, interpret=True)
+
+
+def test_graph_aggregate_refuses_unaligned_f_block():
+    adj = jnp.zeros((1, 8, 8))
+    x = jnp.zeros((1, 8, 16))
+    w = jnp.zeros((16, 192))
+    with pytest.raises(ValueError, match="multiple of 128"):
+        graph_aggregate(adj, x, w, block_f=64, interpret=True)
+
+
+def test_block_candidates_are_lane_aligned():
+    from repro.kernels.graph_aggregate.ops import (
+        block_candidates as f_blocks,
+    )
+    for cands in (block_candidates(32), block_candidates(4096),
+                  f_blocks(64), f_blocks(512)):
+        assert cands and all(b % 128 == 0 for b in cands)
+
+
+def test_interpret_mode_off_tpu():
+    """The model's kernel call sites interpret off-TPU and never on it."""
+    import jax
+    from repro.kernels import interpret_mode
+    assert interpret_mode() == (jax.default_backend() != "tpu")

@@ -378,6 +378,13 @@ def test_config_rejects_pallas_with_gat():
         _cfg(gnn="gat", use_pallas_aggregate=True)
 
 
+def test_config_rejects_pallas_with_segmented():
+    """A whole-program segment pack has no node bound, so it cannot take
+    the VMEM-resident segment_aggregate kernel."""
+    with pytest.raises(ValueError, match="segmented"):
+        _cfg(adjacency="segmented", use_pallas_aggregate=True)
+
+
 def test_trainer_rejects_int8_precision(tmp_path):
     from repro.training.trainer import CostModelTrainer, TrainerConfig
     mc = _cfg(precision="int8")

@@ -316,3 +316,39 @@ def test_trainer_segmented_whole_model_runs(tmp_path):
     out = tr.run(resume=False)
     assert out["step"] == 2
     assert np.isfinite(out["loss"])
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "fixed"])
+def test_compile_cache_placement(tmp_path, from_env):
+    """With JAX_COMPILATION_CACHE_DIR set the persistent compile cache is
+    written there, as JAX reads it; unset, it goes to the one fixed
+    directory at the root of the checkout."""
+    import subprocess
+    import sys
+    import textwrap
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = textwrap.dedent(f"""
+        import jax, jax.numpy as jnp
+        from repro.launch import COMPILE_CACHE_DIR, enable_compile_cache
+        used = enable_compile_cache()
+        print(used)
+        print(jax.config.jax_compilation_cache_dir)
+        print(COMPILE_CACHE_DIR)
+        if {from_env}:
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+            jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+    """)
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, env=env)
+    assert res.returncode == 0, res.stderr
+    used, configured, fixed = res.stdout.split()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert fixed == os.path.join(root, ".jax_cache")
+    want = str(tmp_path) if from_env else fixed
+    assert used == configured == want
+    if from_env:
+        assert os.listdir(tmp_path)
