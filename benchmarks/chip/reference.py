@@ -1,0 +1,510 @@
+"""Plain reference of the cost model that the benchmark's cells serve and
+train (Kaufman et al., arXiv:2008.01040, section 3; Table 5 as the
+repository's model reads it).
+
+It imports nothing of the `repro` package and takes nothing the program
+made. Graphs arrive in their wire form (the JSON dicts a client sends), the
+features are computed here from the node attributes, the feature normalizer
+and the weights are made here from the seed, and the model is written as a
+dense, per-graph computation: an adjacency matrix per graph in place of the
+program's packed edge lists, one padded row per graph in place of its packs.
+
+Precision: the configurations state float32 weights and activations with
+matmuls at JAX's default precision, which on a TPU rounds each matmul's
+operands to bfloat16 and accumulates in float32. `score(..., jnp.float32)`
+computes just that: every weight matmul at the default precision, the
+neighbour sums (0/1 adjacency matmuls, exact sums in the program) at the
+highest, all else in float32. `jnp.bfloat16` is the control, the same model
+one precision below the configuration's: weights, activations and state in
+bfloat16.
+"""
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+# op name -> (unit, flops per output element, transcendental, elementwise);
+# the index of an op is its position here (the model's opcode vocabulary)
+OPS = {
+    'parameter': ('none', 0.0, 0, 0),
+    'constant': ('none', 0.0, 0, 0),
+    'iota': ('vpu', 0.0, 0, 0),
+    'rng': ('special', 4.0, 1, 0),
+    'negate': ('vpu', 1.0, 0, 1),
+    'abs': ('vpu', 1.0, 0, 1),
+    'exponential': ('special', 4.0, 1, 1),
+    'log': ('special', 4.0, 1, 1),
+    'tanh': ('special', 6.0, 1, 1),
+    'rsqrt': ('special', 2.0, 1, 1),
+    'sqrt': ('special', 2.0, 1, 1),
+    'erf': ('special', 8.0, 1, 1),
+    'logistic': ('special', 5.0, 1, 1),
+    'sign': ('vpu', 1.0, 0, 1),
+    'floor': ('vpu', 1.0, 0, 1),
+    'convert': ('vpu', 1.0, 0, 1),
+    'not': ('vpu', 1.0, 0, 1),
+    'sine': ('special', 6.0, 1, 1),
+    'cosine': ('special', 6.0, 1, 1),
+    'add': ('vpu', 1.0, 0, 1),
+    'subtract': ('vpu', 1.0, 0, 1),
+    'multiply': ('vpu', 1.0, 0, 1),
+    'divide': ('vpu', 3.0, 0, 1),
+    'power': ('special', 8.0, 1, 1),
+    'maximum': ('vpu', 1.0, 0, 1),
+    'minimum': ('vpu', 1.0, 0, 1),
+    'remainder': ('vpu', 4.0, 0, 1),
+    'and': ('vpu', 1.0, 0, 1),
+    'or': ('vpu', 1.0, 0, 1),
+    'compare': ('vpu', 1.0, 0, 1),
+    'select': ('vpu', 1.0, 0, 1),
+    'clamp': ('vpu', 2.0, 0, 1),
+    'broadcast': ('mem', 0.0, 0, 0),
+    'reshape': ('mem', 0.0, 0, 0),
+    'transpose': ('mem', 0.0, 0, 0),
+    'concatenate': ('mem', 0.0, 0, 0),
+    'slice': ('mem', 0.0, 0, 0),
+    'pad': ('mem', 0.0, 0, 0),
+    'reverse': ('mem', 0.0, 0, 0),
+    'copy': ('mem', 0.0, 0, 0),
+    'dynamic-slice': ('mem', 0.0, 0, 0),
+    'dynamic-update-slice': ('mem', 0.0, 0, 0),
+    'gather': ('mem', 0.0, 0, 0),
+    'scatter': ('mem', 1.0, 0, 0),
+    'reduce-sum': ('vpu', 1.0, 0, 0),
+    'reduce-max': ('vpu', 1.0, 0, 0),
+    'reduce-min': ('vpu', 1.0, 0, 0),
+    'reduce-prod': ('vpu', 1.0, 0, 0),
+    'reduce-and': ('vpu', 1.0, 0, 0),
+    'reduce-or': ('vpu', 1.0, 0, 0),
+    'cumsum': ('vpu', 1.0, 0, 0),
+    'argmax': ('vpu', 2.0, 0, 0),
+    'sort': ('vpu', 8.0, 0, 0),
+    'top-k': ('vpu', 6.0, 0, 0),
+    'dot': ('mxu', 2.0, 0, 0),
+    'convolution': ('mxu', 2.0, 0, 0),
+    'all-reduce': ('mem', 1.0, 0, 0),
+    'all-gather': ('mem', 0.0, 0, 0),
+    'reduce-scatter': ('mem', 1.0, 0, 0),
+    'all-to-all': ('mem', 0.0, 0, 0),
+    'collective-permute': ('mem', 0.0, 0, 0),
+    'custom-call': ('vpu', 2.0, 0, 0),
+    'while': ('none', 0.0, 0, 0),
+    'scan': ('none', 0.0, 0, 0),
+}
+OP_INDEX = {name: i for i, name in enumerate(OPS)}
+NUM_OPCODES = len(OPS)
+NODE_FEATS = 31      # shape 6+3, 7 scalars, reduced 2+3, filter 2+3, 1, 2, 2
+KERNEL_FEATS = 15    # tile 6+3, 4 static performance features, nodes, depth
+
+
+# ----------------------------------------------------------------------------
+# features (paper section 3.1), from the wire form of a graph
+# ----------------------------------------------------------------------------
+def _subvec(values, k: int) -> list[float]:
+    """Variable-length list -> first k values (zero padded), sum, product,
+    log1p(product); the product of an empty list is 0."""
+    vals = [float(v) for v in values]
+    head = vals[:k] + [0.0] * (k - min(len(vals), k))
+    prod = math.prod(vals) if vals else 0.0
+    return head + [sum(vals), prod, math.log1p(prod)]
+
+
+def _node_flops(n: dict) -> float:
+    unit, per_elem, _, _ = OPS[n["op"]]
+    vol = math.prod(int(d) for d in n["shape"])
+    k = max(int(n["contract_dim"]), 1)
+    if n["op"] == "dot":
+        return 2.0 * vol * k
+    if n["op"] == "convolution":
+        kh, kw = n["filter_size"]
+        return 2.0 * vol * k * max(int(kh), 1) * max(int(kw), 1)
+    if unit in ("mem", "none"):
+        return 0.0
+    red = math.prod(max(int(d), 1) for d in n["reduced_dims"])
+    return per_elem * vol * red
+
+
+def featurize(g: dict) -> dict:
+    """Raw (unnormalized) features of one graph: opcode ids [n], node
+    features [n, 31] and kernel features [15] in float64, and the unique
+    directed edges [e, 2] as (src, dst)."""
+    nodes = g["nodes"]
+    n = len(nodes)
+    fan_out = [0] * n
+    edges, seen = [], set()
+    for d, nd in enumerate(nodes):
+        for s in nd["inputs"]:
+            fan_out[s] += 1
+            if (s, d) not in seen:
+                seen.add((s, d))
+                edges.append((s, d))
+    feats = np.zeros((n, NODE_FEATS), np.float64)
+    depth = [0] * n
+    flops = bytes_read = bytes_written = trans = 0.0
+    outputs = [i for i, nd in enumerate(nodes) if nd["is_output"]] or [n - 1]
+    for i, nd in enumerate(nodes):
+        unit, _, is_trans, is_ew = OPS[nd["op"]]
+        vol = math.prod(int(d) for d in nd["shape"])
+        nbytes = vol * int(nd["dtype_bytes"])
+        f = _node_flops(nd)
+        row = _subvec(nd["shape"], 6)
+        row += [float(len(nd["shape"])), float(nd["dtype_bytes"]), 1.0,
+                1.0 if nd["op"] == "parameter" else 0.0,
+                1.0 if nd["is_output"] else 0.0,
+                float(len(nd["inputs"])), float(fan_out[i])]
+        row += _subvec(nd["reduced_dims"], 2)
+        row += _subvec(nd["filter_size"] if nd["op"] == "convolution"
+                       else (), 2)
+        row += [float(nd["contract_dim"]), math.log1p(f),
+                math.log1p(float(nbytes)), float(is_ew), float(is_trans)]
+        feats[i] = row
+        depth[i] = 1 + max((depth[j] for j in nd["inputs"]), default=0)
+        flops += f
+        if nd["op"] in ("parameter", "constant"):
+            bytes_read += nbytes
+        if is_trans:
+            trans += vol
+    for i in outputs:
+        nd = nodes[i]
+        bytes_written += (math.prod(int(d) for d in nd["shape"])
+                          * int(nd["dtype_bytes"]))
+    kf = _subvec(g["tile_size"], 6) + [
+        math.log1p(flops), math.log1p(bytes_read), math.log1p(bytes_written),
+        math.log1p(trans), float(n), float(max(depth, default=0))]
+    return {"opcodes": np.array([OP_INDEX[nd["op"]] for nd in nodes],
+                                np.int32),
+            "node_feats": feats,
+            "kernel_feats": np.asarray(kf, np.float64),
+            "edges": np.asarray(edges, np.int64).reshape(-1, 2)}
+
+
+def fit_normalizer(feats: list[dict]) -> dict:
+    """Per-feature minimum and maximum over graphs featurized here."""
+    nf = np.concatenate([f["node_feats"] for f in feats], axis=0)
+    kf = np.stack([f["kernel_feats"] for f in feats], axis=0)
+    return {"node_min": nf.min(0), "node_max": nf.max(0),
+            "kernel_min": kf.min(0), "kernel_max": kf.max(0)}
+
+
+def _scale(x, lo, hi):
+    return np.clip((x - lo) / np.maximum(hi - lo, 1e-9), 0.0, 1.0)
+
+
+def dense_batch(feats: list[dict], norm: dict, num_nodes: int) -> dict:
+    """Graphs padded to `num_nodes` rows each, min-max scaled as the
+    configuration's features are (float64, then float32), with a dense
+    adjacency adj[b, d, s] = 1 for every edge s -> d."""
+    b = len(feats)
+    out = {"opcodes": np.zeros((b, num_nodes), np.int32),
+           "node_feats": np.zeros((b, num_nodes, NODE_FEATS), np.float32),
+           "kernel_feats": np.zeros((b, KERNEL_FEATS), np.float32),
+           "adj": np.zeros((b, num_nodes, num_nodes), np.float32),
+           "mask": np.zeros((b, num_nodes), np.float32)}
+    for i, f in enumerate(feats):
+        n = f["opcodes"].shape[0]
+        if n > num_nodes:
+            raise ValueError(f"graph of {n} nodes > {num_nodes} rows")
+        out["opcodes"][i, :n] = f["opcodes"]
+        out["node_feats"][i, :n] = _scale(f["node_feats"], norm["node_min"],
+                                          norm["node_max"])
+        out["kernel_feats"][i] = _scale(f["kernel_feats"],
+                                        norm["kernel_min"],
+                                        norm["kernel_max"])
+        e = f["edges"]
+        if e.size:
+            out["adj"][i, e[:, 1], e[:, 0]] = 1.0
+        out["mask"][i, :n] = 1.0
+    return out
+
+
+# ----------------------------------------------------------------------------
+# weights, made from the seed in the tree layout the program takes
+# ----------------------------------------------------------------------------
+def param_shapes(cfg: dict) -> dict:
+    """{path: shape} of every weight of the model `cfg` describes."""
+    want = {"gnn": "graphsage", "aggregator": "mean", "directed": True,
+            "kernel_feat_mode": "node", "include_static_perf": True,
+            "include_tile": True, "scan_layers": False}
+    for k, v in want.items():
+        if cfg.get(k, v) != v:
+            raise ValueError(f"the reference supports {k}={v!r} only")
+    d = cfg["hidden_dim"]
+    in_dim = cfg["opcode_embed_dim"] + NODE_FEATS + KERNEL_FEATS
+    shapes = {("opcode_embed", "table"): (NUM_OPCODES,
+                                          cfg["opcode_embed_dim"]),
+              ("f1", "w"): (in_dim, d),
+              ("head", "w"): (d, 1)}
+    for i in range(cfg["gnn_layers"]):
+        for name, rows in (("f2_in", d), ("f2_out", d), ("f3", 3 * d)):
+            shapes[("gnn", "layers", i, name, "w")] = (rows, d)
+    for i in range(cfg["node_final_layers"]):
+        shapes[("node_final", "layers", i, "w")] = (d, d)
+    if cfg["reduction"] == "lstm":
+        for name, shape in (("wx", (d, 4 * d)), ("wh", (d, 4 * d)),
+                            ("b", (4 * d,))):
+            shapes[("reduction", "lstm", name)] = shape
+    elif cfg["reduction"] == "transformer":
+        for i in range(cfg["transformer_layers"]):
+            blk = ("reduction", "encoder", "blocks", i)
+            for ln in ("ln1", "ln2"):
+                shapes[blk + (ln, "scale")] = (d,)
+                shapes[blk + (ln, "bias")] = (d,)
+            for name in ("q", "k", "v", "o"):
+                shapes[blk + ("attn", name, "w")] = (d, d)
+            shapes[blk + ("fc1", "w")] = (d, 4 * d)
+            shapes[blk + ("fc1", "b")] = (4 * d,)
+            shapes[blk + ("fc2", "w")] = (4 * d, d)
+            shapes[blk + ("fc2", "b")] = (d,)
+        shapes[("reduction", "encoder", "ln_f", "scale")] = (d,)
+        shapes[("reduction", "encoder", "ln_f", "bias")] = (d,)
+    else:
+        raise ValueError(f"no reference for reduction {cfg['reduction']!r}")
+    return shapes
+
+
+def _nest(flat: dict):
+    """{path: leaf} -> nested dicts, with integer path parts as lists."""
+    root: dict = {}
+    for path, leaf in flat.items():
+        node = root
+        for part, nxt in zip(path[:-1], path[1:]):
+            node = node.setdefault(part, {})
+        node[path[-1]] = leaf
+
+    def lists(x):
+        if not isinstance(x, dict):
+            return x
+        if x and all(isinstance(k, int) for k in x):
+            return [lists(x[i]) for i in range(len(x))]
+        return {k: lists(v) for k, v in x.items()}
+    return lists(root)
+
+
+def init_params(key, cfg: dict):
+    """Weights from `key`: Glorot-uniform matrices, a N(0, 0.02²)
+    embedding, zero biases, unit layer-norm scales (float32). Call it under
+    `jax.jit` with `cfg` static: one device call makes the whole tree."""
+    import jax
+    import jax.numpy as jnp
+    flat = {}
+    shapes = param_shapes(cfg)
+    keys = jax.random.split(key, len(shapes))
+    for k, (path, shape) in zip(keys, sorted(shapes.items(), key=str)):
+        leaf = path[-1]
+        if path[0] == "opcode_embed":
+            flat[path] = jax.random.normal(k, shape, jnp.float32) * 0.02
+        elif leaf == "scale":
+            flat[path] = jnp.ones(shape, jnp.float32)
+        elif leaf in ("bias", "b"):
+            flat[path] = jnp.zeros(shape, jnp.float32)
+        else:
+            lim = math.sqrt(6.0 / (shape[-2] + shape[-1]))
+            flat[path] = jax.random.uniform(k, shape, jnp.float32, -lim, lim)
+    return _nest(flat)
+
+
+# ----------------------------------------------------------------------------
+# the model (section 3.2), dense and per graph
+# ----------------------------------------------------------------------------
+def _ln(p, x):
+    mu = x.mean(-1, keepdims=True)
+    var = ((x - mu) ** 2).mean(-1, keepdims=True)
+    import jax
+    return (x - mu) * jax.lax.rsqrt(var + 1e-6) * p["scale"] + p["bias"]
+
+
+def _gelu(x):
+    import jax.numpy as jnp
+    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
+                                     * (x + 0.044715 * x ** 3)))
+
+
+def node_embeddings(params, cfg: dict, b: dict, node_keep=None):
+    """f1 and the directed GraphSAGE layers (mean aggregation, separate
+    in- and out-edge modules, l2-normalized outputs), then the node-final
+    MLP. `node_keep`, if given, is the dropout keep mask [B, N, D] applied
+    before the node-final MLP, scaled by 1/keep."""
+    import jax
+    import jax.numpy as jnp
+    from jax.lax import Precision
+    HIGHEST = Precision.HIGHEST
+    relu = jax.nn.relu
+    mask = b["mask"][..., None]
+    adj = b["adj"]
+    adj_t = jnp.swapaxes(adj, 1, 2)
+    emb = params["opcode_embed"]["table"][b["opcodes"]]
+    kf = jnp.broadcast_to(b["kernel_feats"][:, None, :],
+                          b["node_feats"].shape[:2]
+                          + (b["kernel_feats"].shape[-1],))
+    x = jnp.concatenate([emb, b["node_feats"], kf], axis=-1)
+    h = relu(x @ params["f1"]["w"]) * mask
+    deg_in = jnp.maximum(adj.sum(-1, keepdims=True), 1.0)
+    deg_out = jnp.maximum(adj_t.sum(-1, keepdims=True), 1.0)
+    for layer in params["gnn"]["layers"]:
+        m_in = relu(h @ layer["f2_in"]["w"]) * mask
+        m_out = relu(h @ layer["f2_out"]["w"]) * mask
+        agg_in = jnp.matmul(adj, m_in, precision=HIGHEST) / deg_in
+        agg_out = jnp.matmul(adj_t, m_out, precision=HIGHEST) / deg_out
+        h = relu(jnp.concatenate([h, agg_in, agg_out], -1)
+                 @ layer["f3"]["w"])
+        h = h * jax.lax.rsqrt((h * h).sum(-1, keepdims=True) + 1e-6) * mask
+    if node_keep is not None:
+        rate = cfg["dropout"]
+        h = jnp.where(node_keep, h / (1.0 - rate), 0.0)
+    for layer in params["node_final"]["layers"]:
+        h = relu(h @ layer["w"])
+    return h * mask
+
+
+def _lstm(p, xs, mask):
+    import jax
+    import jax.numpy as jnp
+    bsz = xs.shape[0]
+    hid = p["wh"].shape[0]
+    h0 = jnp.zeros((bsz, hid), xs.dtype)
+
+    def step(carry, inp):
+        h, c = carry
+        x, m = inp
+        gates = x @ p["wx"] + h @ p["wh"] + p["b"]
+        i, f, g, o = jnp.split(gates, 4, axis=-1)
+        c_new = (jax.nn.sigmoid(f + 1.0) * c
+                 + jax.nn.sigmoid(i) * jnp.tanh(g))
+        h_new = jax.nn.sigmoid(o) * jnp.tanh(c_new)
+        m = m[:, None]
+        return (m * h_new + (1 - m) * h, m * c_new + (1 - m) * c), None
+
+    (h, _), _ = jax.lax.scan(step, (h0, h0), (jnp.swapaxes(xs, 0, 1),
+                                             jnp.swapaxes(mask, 0, 1)))
+    return h
+
+
+def _encoder(p, x, mask, heads: int):
+    import jax
+    import jax.numpy as jnp
+    bsz, n, d = x.shape
+    hd = d // heads
+    for blk in p["blocks"]:
+        y = _ln(blk["ln1"], x)
+        q, k, v = (
+            (y @ blk["attn"][name]["w"]).reshape(bsz, n, heads, hd)
+            for name in ("q", "k", "v"))
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
+        logits = jnp.where(mask[:, None, None, :] > 0, logits,
+                           jnp.finfo(logits.dtype).min)
+        att = jax.nn.softmax(logits, axis=-1)
+        y = jnp.einsum("bhqk,bkhd->bqhd", att, v).reshape(bsz, n, d)
+        x = x + y @ blk["attn"]["o"]["w"]
+        y = _gelu(_ln(blk["ln2"], x) @ blk["fc1"]["w"] + blk["fc1"]["b"])
+        x = x + y @ blk["fc2"]["w"] + blk["fc2"]["b"]
+    return _ln(p["ln_f"], x)
+
+
+def forward(params, cfg: dict, b: dict, node_keep=None):
+    """Scores [B] of a dense batch (`dense_batch`), in the dtype of
+    `params` and `b`."""
+    h = node_embeddings(params, cfg, b, node_keep)
+    red = params["reduction"]
+    if cfg["reduction"] == "lstm":
+        kappa = _lstm(red["lstm"], h, b["mask"])
+    else:
+        enc = _encoder(red["encoder"], h, b["mask"],
+                       cfg["transformer_heads"])
+        kappa = (enc * b["mask"][..., None]).sum(1)
+    return (kappa @ params["head"]["w"])[:, 0]
+
+
+def cast(tree, dtype):
+    """Every floating leaf of `tree` in `dtype`."""
+    import jax
+    import jax.numpy as jnp
+    return jax.tree_util.tree_map(
+        lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating)
+        else x, tree)
+
+
+def pad_rows(n: int) -> int:
+    """Rows a graph of n nodes is padded to here: the next power of two,
+    so that a sample compiles a few shapes and not one per size."""
+    return 1 << max(int(n) - 1, 0).bit_length()
+
+
+def score(params, cfg: dict, feats: list[dict], norm: dict, dtype) -> \
+        np.ndarray:
+    """Reference scores of featurized graphs, grouped by padded size and
+    computed on the default device in `dtype` (float32 as the
+    configuration states it, or bfloat16 for the control)."""
+    import jax
+    import jax.numpy as jnp
+    fwd = jax.jit(forward, static_argnums=(1,))
+    static = _Frozen(cfg)
+    out = np.zeros((len(feats),), np.float64)
+    groups: dict[int, list[int]] = {}
+    for i, f in enumerate(feats):
+        groups.setdefault(pad_rows(f["opcodes"].shape[0]), []).append(i)
+    p = cast(params, dtype)
+    with jax.default_matmul_precision("default"):
+        for rows, idx in sorted(groups.items()):
+            # a few graphs per call keep the dense [B, N, N] blocks small;
+            # the last call is filled up with its first graph, so each
+            # padded size compiles once
+            step = max(1, min(len(idx), (1 << 22) // (rows * rows)))
+            for s in range(0, len(idx), step):
+                part = idx[s:s + step]
+                fill = part + [part[0]] * (step - len(part))
+                b = dense_batch([feats[i] for i in fill], norm, rows)
+                b = cast({k: jnp.asarray(v) for k, v in b.items()}, dtype)
+                got = np.asarray(fwd(p, static, b), np.float64)
+                out[part] = got[:len(part)]
+    return out
+
+
+class _Frozen(dict):
+    """A configuration dict that `jax.jit` can take as a static argument."""
+
+    def __hash__(self):
+        return hash(tuple(sorted((k, str(v)) for k, v in self.items())))
+
+
+def frozen(cfg: dict) -> dict:
+    return _Frozen(cfg)
+
+
+# ----------------------------------------------------------------------------
+# training (section 3.3): pairwise rank loss, global-norm clip, AdamW
+# ----------------------------------------------------------------------------
+def rank_loss(preds, targets, groups, valid):
+    """Pairwise hinge rank loss, Eq. (1): pairs of one kernel (group), both
+    valid, where the first is truly slower; over n(n-1)/2."""
+    import jax
+    import jax.numpy as jnp
+    n = preds.shape[0]
+    pair = ((targets[:, None] - targets[None, :]) > 0).astype(preds.dtype)
+    pair = pair * (groups[:, None] == groups[None, :]).astype(preds.dtype)
+    pair = pair * valid[:, None] * valid[None, :]
+    pair = pair * (1.0 - jnp.eye(n, dtype=preds.dtype))
+    dz = preds[:, None] - preds[None, :]
+    return (jax.nn.relu(1.0 - dz) * pair).sum() / (n * (n - 1) / 2.0)
+
+
+def adamw(params, grads, m, v, step: int, opt: dict):
+    """One AdamW step (no weight decay) after clipping the gradient's global
+    norm; the learning rate decays exponentially. Returns (params, m, v,
+    clipped grads)."""
+    import jax
+    import jax.numpy as jnp
+    leaves = jax.tree_util.tree_leaves(grads)
+    gn = jnp.sqrt(sum((g * g).sum() for g in leaves))
+    clip = jnp.minimum(1.0, opt["grad_clip_norm"] / jnp.maximum(gn, 1e-12))
+    grads = jax.tree_util.tree_map(lambda g: g * clip, grads)
+    b1, b2 = opt["b1"], opt["b2"]
+    lr = opt["lr"] * opt["lr_decay"] ** (step / opt["decay_every"])
+    m = jax.tree_util.tree_map(lambda a, g: b1 * a + (1 - b1) * g, m, grads)
+    v = jax.tree_util.tree_map(lambda a, g: b2 * a + (1 - b2) * g * g, v,
+                               grads)
+    mh, vh = 1.0 / (1.0 - b1 ** step), 1.0 / (1.0 - b2 ** step)
+    params = jax.tree_util.tree_map(
+        lambda p, a, s: p - lr * (a * mh) / (jnp.sqrt(s * vh) + opt["eps"]),
+        params, m, v)
+    return params, m, v, grads
