@@ -181,42 +181,50 @@ def cost_model_apply(params: dict, cfg: CostModelConfig, batch,
     if not cfg.include_static_perf:
         kfeats = kfeats.at[:, F.STATIC_PERF_SLICE].set(0.0)
 
-    emb = embedding_apply(params["opcode_embed"], opcodes)      # [B,N,E]
-    x = jnp.concatenate([emb, node_feats], axis=-1)
-    if cfg.kernel_feat_mode == "node":
-        B, N = opcodes.shape
-        kf = jnp.broadcast_to(kfeats[:, None, :], (B, N, kfeats.shape[-1]))
-        x = jnp.concatenate([x, kf], axis=-1)
+    with jax.named_scope("embed"):
+        emb = embedding_apply(params["opcode_embed"], opcodes)  # [B,N,E]
+        x = jnp.concatenate([emb, node_feats], axis=-1)
+        if cfg.kernel_feat_mode == "node":
+            B, N = opcodes.shape
+            kf = jnp.broadcast_to(kfeats[:, None, :],
+                                  (B, N, kfeats.shape[-1]))
+            x = jnp.concatenate([x, kf], axis=-1)
+        eps = jax.nn.relu(dense_apply(params["f1"], x)) * mask[..., None]
 
-    eps = jax.nn.relu(dense_apply(params["f1"], x)) * mask[..., None]
+    with jax.named_scope("gnn"):
+        if cfg.gnn == "graphsage":
+            eps = G.sage_apply(params["gnn"], eps, adj, mask,
+                               aggregator=cfg.aggregator,
+                               directed=cfg.directed,
+                               use_pallas=cfg.use_pallas_aggregate)
+        elif cfg.gnn == "gat":
+            eps = G.gat_apply(params["gnn"], eps, adj, mask,
+                              num_heads=cfg.gat_heads, directed=cfg.directed)
 
-    if cfg.gnn == "graphsage":
-        eps = G.sage_apply(params["gnn"], eps, adj, mask,
-                           aggregator=cfg.aggregator, directed=cfg.directed,
-                           use_pallas=cfg.use_pallas_aggregate)
-    elif cfg.gnn == "gat":
-        eps = G.gat_apply(params["gnn"], eps, adj, mask,
-                          num_heads=cfg.gat_heads, directed=cfg.directed)
-
-    sub = None if rng is None else jax.random.fold_in(rng, 1)
-    eps = dropout(sub, eps, cfg.dropout, deterministic)
-    eps = mlp_apply(params["node_final"], eps, final_act=True)
-    eps = eps * mask[..., None]
+    with jax.named_scope("node_final"):
+        sub = None if rng is None else jax.random.fold_in(rng, 1)
+        eps = dropout(sub, eps, cfg.dropout, deterministic)
+        eps = mlp_apply(params["node_final"], eps, final_act=True)
+        eps = eps * mask[..., None]
 
     if cfg.reduction == "per_node":
-        per_node = dense_apply(params["node_head"], eps)[..., 0]  # [B,N]
-        y = jnp.sum(per_node * mask, axis=1)
-        if cfg.kernel_feat_mode == "kernel":
-            y = y + dense_apply(params["kernel_head"], kfeats)[..., 0]
+        with jax.named_scope("head"):
+            per_node = dense_apply(params["node_head"], eps)[..., 0]
+            y = jnp.sum(per_node * mask, axis=1)
+            if cfg.kernel_feat_mode == "kernel":
+                y = y + dense_apply(params["kernel_head"], kfeats)[..., 0]
         return y
 
-    kappa = R.reduction_apply(params["reduction"], cfg.reduction, eps, mask,
-                              transformer_heads=cfg.transformer_heads,
-                              rng=rng, dropout_rate=cfg.dropout,
-                              deterministic=deterministic)
-    if cfg.kernel_feat_mode == "kernel":
-        kappa = jnp.concatenate([kappa, kfeats], axis=-1)
-    return dense_apply(params["head"], kappa)[..., 0]
+    with jax.named_scope("reduction"):
+        kappa = R.reduction_apply(params["reduction"], cfg.reduction, eps,
+                                  mask,
+                                  transformer_heads=cfg.transformer_heads,
+                                  rng=rng, dropout_rate=cfg.dropout,
+                                  deterministic=deterministic)
+    with jax.named_scope("head"):
+        if cfg.kernel_feat_mode == "kernel":
+            kappa = jnp.concatenate([kappa, kfeats], axis=-1)
+        return dense_apply(params["head"], kappa)[..., 0]
 
 
 def _mask_kernel_feats(cfg: CostModelConfig, kfeats: jnp.ndarray):
@@ -234,28 +242,27 @@ def _embed_sparse(params: dict, cfg: CostModelConfig, batch) -> jnp.ndarray:
     mask = batch.node_mask                       # [M]
     kfeats = _mask_kernel_feats(cfg, batch.kernel_feats)
 
-    emb = embedding_apply(params["opcode_embed"], batch.opcodes)  # [M, E]
-    x = jnp.concatenate([emb, batch.node_feats], axis=-1)
-    if cfg.kernel_feat_mode == "node":
-        x = jnp.concatenate(
-            [x, jnp.take(kfeats, batch.graph_ids, axis=0)], axis=-1)
-
-    eps = jax.nn.relu(dense_apply(params["f1"], x)) * mask[:, None]
-
-    if cfg.gnn == "graphsage":
-        if cfg.use_pallas_aggregate:
+    with jax.named_scope("embed"):
+        emb = embedding_apply(params["opcode_embed"], batch.opcodes)
+        x = jnp.concatenate([emb, batch.node_feats], axis=-1)   # [M, ·]
+        if cfg.kernel_feat_mode == "node":
+            x = jnp.concatenate(
+                [x, jnp.take(kfeats, batch.graph_ids, axis=0)], axis=-1)
+        eps = jax.nn.relu(dense_apply(params["f1"], x)) * mask[:, None]
+    with jax.named_scope("gnn"):
+        if cfg.gnn == "graphsage" and cfg.use_pallas_aggregate:
             # fused kernels/segment_aggregate path (f32 or int8 f2 weights)
             eps = G.sage_apply_sparse_q(params["gnn"], eps, batch.edge_src,
                                         batch.edge_dst, batch.edge_mask,
                                         mask, aggregator=cfg.aggregator,
                                         directed=cfg.directed)
-        else:
+        elif cfg.gnn == "graphsage":
             eps = G.sage_apply_sparse(params["gnn"], eps, batch.edge_src,
                                       batch.edge_dst, batch.edge_mask, mask,
                                       aggregator=cfg.aggregator,
                                       directed=cfg.directed)
-    elif cfg.gnn == "gat":
-        eps = G.gat_apply_sparse(params["gnn"], eps, batch.edge_src,
+        elif cfg.gnn == "gat":
+            eps = G.gat_apply_sparse(params["gnn"], eps, batch.edge_src,
                                      batch.edge_dst, batch.edge_mask, mask,
                                      num_heads=cfg.gat_heads,
                                      directed=cfg.directed)
@@ -305,45 +312,51 @@ def _readout_sparse(params: dict, cfg: CostModelConfig, eps: jnp.ndarray,
     num_graphs = kfeats.shape[0]
     kfeats = _mask_kernel_feats(cfg, kfeats)
 
-    sub = None if rng is None else jax.random.fold_in(rng, 1)
-    eps = dropout(sub, eps, cfg.dropout, deterministic)
-    eps = mlp_apply(params["node_final"], eps, final_act=True)
-    eps = eps * mask[:, None]
+    with jax.named_scope("node_final"):
+        sub = None if rng is None else jax.random.fold_in(rng, 1)
+        eps = dropout(sub, eps, cfg.dropout, deterministic)
+        eps = mlp_apply(params["node_final"], eps, final_act=True)
+        eps = eps * mask[:, None]
 
     if cfg.reduction == "per_node":
-        per_node = dense_apply(params["node_head"], eps)[..., 0]   # [M]
-        y = jax.ops.segment_sum(per_node * mask, gids, num_segments=num_graphs)
-        if cfg.kernel_feat_mode == "kernel":
-            y = y + dense_apply(params["kernel_head"], kfeats)[..., 0]
+        with jax.named_scope("head"):
+            per_node = dense_apply(params["node_head"], eps)[..., 0]  # [M]
+            y = jax.ops.segment_sum(per_node * mask, gids,
+                                    num_segments=num_graphs)
+            if cfg.kernel_feat_mode == "kernel":
+                y = y + dense_apply(params["kernel_head"], kfeats)[..., 0]
         return y
 
-    if cfg.reduction == "column_wise":
-        s = jax.ops.segment_sum(eps * mask[:, None], gids,
-                                num_segments=num_graphs)
-        cnt = jax.ops.segment_sum(mask, gids, num_segments=num_graphs)
-        n = jnp.maximum(cnt, 1.0)
-        neg = jnp.finfo(eps.dtype).min
-        mx = jax.ops.segment_max(jnp.where(mask[:, None] > 0, eps, neg),
-                                 gids, num_segments=num_graphs)
-        # padding graph slots have no nodes; zero them instead of -inf/min
-        # so the head stays finite (their predictions are masked by `valid`)
-        mx = jnp.where(cnt[:, None] > 0, mx, 0.0)
-        kappa = jnp.concatenate([s / n[:, None], mx], axis=-1)
-    else:
-        # sequence reductions (LSTM/Transformer) need per-graph node order;
-        # gather the flat buffer into [G, R, D] (R = packed reduce capacity,
-        # typically ≪ the dense path's max_nodes × slot padding)
-        eps_pad = jnp.concatenate(
-            [eps, jnp.zeros((1, eps.shape[-1]), eps.dtype)], axis=0)
-        seq = jnp.take(eps_pad, gather_idx, axis=0)                # [G, R, D]
-        kappa = R.reduction_apply(params["reduction"], cfg.reduction, seq,
-                                  gather_mask,
-                                  transformer_heads=cfg.transformer_heads,
-                                  rng=rng, dropout_rate=cfg.dropout,
-                                  deterministic=deterministic)
-    if cfg.kernel_feat_mode == "kernel":
-        kappa = jnp.concatenate([kappa, kfeats], axis=-1)
-    return dense_apply(params["head"], kappa)[..., 0]
+    with jax.named_scope("reduction"):
+        if cfg.reduction == "column_wise":
+            s = jax.ops.segment_sum(eps * mask[:, None], gids,
+                                    num_segments=num_graphs)
+            cnt = jax.ops.segment_sum(mask, gids, num_segments=num_graphs)
+            n = jnp.maximum(cnt, 1.0)
+            neg = jnp.finfo(eps.dtype).min
+            mx = jax.ops.segment_max(jnp.where(mask[:, None] > 0, eps, neg),
+                                     gids, num_segments=num_graphs)
+            # padding graph slots have no nodes; zero them instead of
+            # -inf/min so the head stays finite (their predictions are
+            # masked by `valid`)
+            mx = jnp.where(cnt[:, None] > 0, mx, 0.0)
+            kappa = jnp.concatenate([s / n[:, None], mx], axis=-1)
+        else:
+            # sequence reductions (LSTM/Transformer) need per-graph node
+            # order; gather the flat buffer into [G, R, D] (R = packed
+            # reduce capacity, typically ≪ the dense path's max_nodes ×
+            # slot padding)
+            eps_pad = jnp.concatenate(
+                [eps, jnp.zeros((1, eps.shape[-1]), eps.dtype)], axis=0)
+            seq = jnp.take(eps_pad, gather_idx, axis=0)        # [G, R, D]
+            kappa = R.reduction_apply(
+                params["reduction"], cfg.reduction, seq, gather_mask,
+                transformer_heads=cfg.transformer_heads, rng=rng,
+                dropout_rate=cfg.dropout, deterministic=deterministic)
+    with jax.named_scope("head"):
+        if cfg.kernel_feat_mode == "kernel":
+            kappa = jnp.concatenate([kappa, kfeats], axis=-1)
+        return dense_apply(params["head"], kappa)[..., 0]
 
 
 def param_count(params) -> int:

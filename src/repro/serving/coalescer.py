@@ -42,6 +42,7 @@ from typing import Callable, Sequence
 import numpy as np
 
 from repro.core.graph import KernelGraph
+from repro.spans import span
 
 ScoreFn = Callable[[Sequence[KernelGraph]], np.ndarray]
 
@@ -82,7 +83,6 @@ class RequestCoalescer:
         self.coalesced = 0            # duplicate adds absorbed by a ticket
         # bounded history (long-lived services flush millions of times)
         self.flush_sizes: deque[int] = deque(maxlen=4096)  # graphs per flush
-        self.flush_nodes: deque[int] = deque(maxlen=4096)  # nodes per flush
 
     @property
     def pending(self) -> int:
@@ -121,13 +121,14 @@ class RequestCoalescer:
             tickets = [self._pending[k][1] for k in keys]
             self._pending = {}
             self._pending_nodes = 0
-            preds = np.asarray(self.score_fn(graphs), np.float32)
+            with span("repro.serve.flush", graphs=len(graphs),
+                      nodes=sum(g.num_nodes for g in graphs)):
+                preds = np.asarray(self.score_fn(graphs), np.float32)
             if preds.shape != (len(graphs),):
                 raise ValueError(f"score_fn returned shape {preds.shape}, "
                                  f"expected ({len(graphs)},)")
             self.flushes += 1
             self.flush_sizes.append(len(graphs))
-            self.flush_nodes.append(sum(g.num_nodes for g in graphs))
             for key, ticket, p in zip(keys, tickets, preds):
                 ticket.value = float(p)
                 if self.on_scored is not None:

@@ -56,6 +56,7 @@ import time
 from dataclasses import dataclass
 
 from repro.core.graph import KernelGraph
+from repro.spans import span
 
 # --------------------------------------------------------------------------
 # Framing
@@ -103,8 +104,9 @@ def _recv_exact(sock: socket.socket, n: int) -> bytes | None:
     return b"".join(chunks)
 
 
-def recv_frame(sock: socket.socket) -> dict | None:
-    """Read one frame off `sock`; None on clean EOF before a frame starts."""
+def recv_body(sock: socket.socket) -> bytes | None:
+    """Read one frame's body off `sock`, undecoded; None on clean EOF
+    before a frame starts."""
     head = _recv_exact(sock, _LEN.size)
     if head is None:
         return None
@@ -115,7 +117,13 @@ def recv_frame(sock: socket.socket) -> dict | None:
     body = _recv_exact(sock, length)
     if body is None:
         raise FrameError("connection closed between length and body")
-    return unpack_frame(body)
+    return body
+
+
+def recv_frame(sock: socket.socket) -> dict | None:
+    """Read one frame off `sock`; None on clean EOF before a frame starts."""
+    body = recv_body(sock)
+    return None if body is None else unpack_frame(body)
 
 
 def send_frame(sock: socket.socket, doc: dict) -> None:
@@ -199,6 +207,15 @@ class _Work:
     deadline: float | None            # absolute time.monotonic() cutoff
     fault: FaultPolicy | None
     seq: int
+    admitted: float = 0.0             # time.perf_counter() at admission
+    wait: object = None               # open `repro.serve.queue_wait` span
+
+    def picked(self) -> None:
+        """Close the request's queue-wait span (once): the worker took it,
+        or it leaves the queue unscored."""
+        if self.wait is not None:
+            self.wait.__exit__(None, None, None)
+            self.wait = None
 
 
 _STOP = object()                      # queue sentinel
@@ -251,6 +268,8 @@ class CostModelServer:
         self.fault_policy = fault_policy
         self.allow_request_faults = bool(allow_request_faults)
         self.stats = ServerStats()
+        # the service's per-request latency record, when it keeps one
+        self._record_latency = getattr(service, "record_latency", None)
         self._queue: queue.Queue = queue.Queue(maxsize=self.max_queue)
         self._listener: socket.socket | None = None
         self._threads: list[threading.Thread] = []
@@ -320,6 +339,7 @@ class CostModelServer:
             except queue.Empty:
                 break
             if w is not _STOP:
+                w.picked()
                 self._respond_error(w, "shutting_down",
                                     "server stopped before scoring")
         with self._lock:
@@ -378,20 +398,27 @@ class CostModelServer:
         try:
             while self._running:
                 try:
-                    req = recv_frame(conn)
+                    body = recv_body(conn)
+                    if body is None:
+                        break          # client closed cleanly
+                    with span("repro.serve.decode") as sp:
+                        req = unpack_frame(body)
+                        graphs = None
+                        if req.get("op") == "predict":
+                            graphs = _decode_graphs(req)
+                            if isinstance(graphs, list):
+                                sp.set_metadata(graphs=len(graphs))
                 except (FrameError, OSError):
                     break              # protocol violation / reset: drop
-                if req is None:
-                    break              # client closed cleanly
-                self._dispatch(conn, send_lock, req)
+                self._dispatch(conn, send_lock, req, graphs)
         finally:
             self._close_conn(conn)
 
-    def _dispatch(self, conn, send_lock, req: dict) -> None:
+    def _dispatch(self, conn, send_lock, req: dict, graphs) -> None:
         op = req.get("op")
         req_id = req.get("id")
         if op == "predict":
-            self._admit(conn, send_lock, req)
+            self._admit(conn, send_lock, req, graphs)
         elif op == "ping":
             self._send(conn, send_lock,
                        {"id": req_id, "ok": True, "pong": time.time()})
@@ -417,27 +444,31 @@ class CostModelServer:
                        {"id": req_id, "ok": False, "error": "bad_request",
                         "detail": f"unknown op {op!r}"})
 
-    def _admit(self, conn, send_lock, req: dict) -> None:
+    def _admit(self, conn, send_lock, req: dict, graphs) -> None:
+        """Queue a decoded predict request; `graphs` is the request's
+        graphs, or the text of why they did not decode."""
         req_id = req.get("id")
         with self._lock:
             self._seq += 1
             seq = self._seq
             self.stats.requests += 1
         fault = self._fault_for(seq, req)
-        try:
-            graphs = [KernelGraph.from_dict(g) for g in req["graphs"]]
-        except (KeyError, TypeError, ValueError) as e:
+        if isinstance(graphs, str):
             self._send(conn, send_lock,
                        {"id": req_id, "ok": False, "error": "bad_request",
-                        "detail": f"undecodable graphs: {e}"})
+                        "detail": graphs})
             return
         deadline_ms = req.get("deadline_ms", self.default_deadline_ms)
         deadline = (time.monotonic() + float(deadline_ms) / 1e3
                     if deadline_ms is not None else None)
-        work = _Work(conn, send_lock, req_id, graphs, deadline, fault, seq)
+        work = _Work(conn, send_lock, req_id, graphs, deadline, fault, seq,
+                     admitted=time.perf_counter())
+        work.wait = span("repro.serve.queue_wait", seq=seq)
+        work.wait.__enter__()
         try:
             self._queue.put_nowait(work)
         except queue.Full:
+            work.picked()
             with self._lock:
                 self.stats.shed_overloaded += 1
             self._respond_error(work, "overloaded",
@@ -458,47 +489,58 @@ class CostModelServer:
             work = self._queue.get()
             if work is _STOP:
                 return
-            batch = [work]
-            # drain whatever is already queued: cross-client batching
-            while len(batch) < self.coalesce_limit:
-                try:
-                    nxt = self._queue.get_nowait()
-                except queue.Empty:
-                    break
-                if nxt is _STOP:
-                    self._queue.put(nxt)      # re-deliver for ourselves
-                    break
-                batch.append(nxt)
-            now = time.monotonic()
-            ready = []
-            for w in batch:
-                if w.deadline is not None and now > w.deadline:
-                    with self._lock:
-                        self.stats.shed_deadline += 1
-                    self._respond_error(w, "deadline_exceeded",
-                                        "expired while queued")
-                else:
-                    ready.append(w)
-            if not ready:
-                continue
+            with span("repro.serve.pass") as sp:
+                self._pass(work, sp)
+
+    def _pass(self, work: _Work, sp) -> None:
+        """One scoring pass: drain up to `coalesce_limit` queued requests
+        after `work`, shed the expired, score the rest through one flush
+        and answer each."""
+        work.picked()
+        batch = [work]
+        # drain whatever is already queued: cross-client batching
+        while len(batch) < self.coalesce_limit:
             try:
-                pendings = [self.service.submit(w.graphs) for w in ready]
-                for w in ready:
-                    if w.fault is not None and w.fault.mode == "kill_flush":
-                        with self._lock:
-                            self.stats.faults_injected += 1
-                        raise _InjectedFault(f"kill_flush at seq {w.seq}")
-                self.service.flush()
-                results = [p.result() for p in pendings]
-            except Exception as e:             # noqa: BLE001 — keep serving
+                nxt = self._queue.get_nowait()
+            except queue.Empty:
+                break
+            if nxt is _STOP:
+                self._queue.put(nxt)      # re-deliver for ourselves
+                break
+            nxt.picked()
+            batch.append(nxt)
+        now = time.monotonic()
+        ready = []
+        for w in batch:
+            if w.deadline is not None and now > w.deadline:
                 with self._lock:
-                    self.stats.worker_failures += 1
-                for w in ready:
-                    self._respond_error(w, "worker_failure",
-                                        f"{type(e).__name__}: {e}")
-                continue
-            for w, scores in zip(ready, results):
-                self._respond_scores(w, scores)
+                    self.stats.shed_deadline += 1
+                self._respond_error(w, "deadline_exceeded",
+                                    "expired while queued")
+            else:
+                ready.append(w)
+        sp.set_metadata(requests=len(batch),
+                        graphs=sum(len(w.graphs) for w in ready))
+        if not ready:
+            return
+        try:
+            pendings = [self.service.submit(w.graphs) for w in ready]
+            for w in ready:
+                if w.fault is not None and w.fault.mode == "kill_flush":
+                    with self._lock:
+                        self.stats.faults_injected += 1
+                    raise _InjectedFault(f"kill_flush at seq {w.seq}")
+            self.service.flush()
+            results = [p.result() for p in pendings]
+        except Exception as e:             # noqa: BLE001 — keep serving
+            with self._lock:
+                self.stats.worker_failures += 1
+            for w in ready:
+                self._respond_error(w, "worker_failure",
+                                    f"{type(e).__name__}: {e}")
+            return
+        for w, scores in zip(ready, results):
+            self._respond_scores(w, scores)
 
     # -- responses ----------------------------------------------------------
     def _respond_scores(self, w: _Work, scores) -> None:
@@ -506,30 +548,34 @@ class CostModelServer:
             self.stats.completed += 1
         self._respond(w, {"id": w.req_id, "ok": True,
                           "scores": [float(s) for s in scores]})
+        if self._record_latency is not None:
+            self._record_latency((time.perf_counter() - w.admitted) * 1e3)
 
     def _respond_error(self, w: _Work, error: str, detail: str) -> None:
         self._respond(w, {"id": w.req_id, "ok": False, "error": error,
                           "detail": detail})
 
     def _respond(self, w: _Work, doc: dict) -> None:
-        fault = w.fault
-        if fault is not None and fault.mode in ("drop", "delay", "corrupt"):
-            with self._lock:
-                self.stats.faults_injected += 1
-            if fault.mode == "drop":
-                self._close_conn(w.sock)
-                return
-            if fault.mode == "delay":
-                time.sleep(fault.delay_s)
-            elif fault.mode == "corrupt":
-                body = b"\xff" * 24            # framed, but not JSON
-                try:
-                    with w.send_lock:
-                        w.sock.sendall(_LEN.pack(len(body)) + body)
-                except OSError:
-                    pass
-                return
-        self._send(w.sock, w.send_lock, doc)
+        with span("repro.serve.respond"):
+            fault = w.fault
+            if fault is not None and fault.mode in ("drop", "delay",
+                                                    "corrupt"):
+                with self._lock:
+                    self.stats.faults_injected += 1
+                if fault.mode == "drop":
+                    self._close_conn(w.sock)
+                    return
+                if fault.mode == "delay":
+                    time.sleep(fault.delay_s)
+                elif fault.mode == "corrupt":
+                    body = b"\xff" * 24            # framed, but not JSON
+                    try:
+                        with w.send_lock:
+                            w.sock.sendall(_LEN.pack(len(body)) + body)
+                    except OSError:
+                        pass
+                    return
+            self._send(w.sock, w.send_lock, doc)
 
     def _send(self, conn, send_lock, doc: dict) -> None:
         try:
@@ -537,6 +583,15 @@ class CostModelServer:
                 send_frame(conn, doc)
         except OSError:
             self._close_conn(conn)     # client went away; nothing to do
+
+
+def _decode_graphs(req: dict) -> list | str:
+    """The graphs of a predict request, or the text of why they do not
+    decode."""
+    try:
+        return [KernelGraph.from_dict(g) for g in req["graphs"]]
+    except (KeyError, TypeError, ValueError) as e:
+        return f"undecodable graphs: {e}"
 
 
 def _service_stats_doc(service) -> dict:

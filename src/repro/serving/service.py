@@ -42,6 +42,7 @@ from repro.data.batching import BucketSpec, bucket_for, encode_packed, \
     pack_graphs
 from repro.serving.cache import CacheStats, PredictionCache
 from repro.serving.coalescer import RequestCoalescer, Ticket
+from repro.spans import span
 
 
 def _percentile(values: Sequence[float], q: float) -> float:
@@ -78,7 +79,7 @@ class ServiceStats:
     flushes: int
     flush_sizes: tuple[int, ...]  # graphs per flush (last 4096 flushes)
     buckets: dict[BucketSpec | str, BucketStats] = field(default_factory=dict)
-    latency_p50_ms: float = 0.0   # per predict_many call
+    latency_p50_ms: float = 0.0   # per request (`record_latency`)
     latency_p99_ms: float = 0.0
 
     @property
@@ -191,19 +192,27 @@ class CostModelService:
         # inference scores whatever it is handed: kernels beyond the budget
         # keep their historical oversized singleton packs here (the
         # 'segmented' backend routes them through graph segmentation)
-        for pack in pack_graphs(graphs, self.node_budget,
-                                oversized="singleton"):
-            part = [graphs[i] for i in pack]
-            spec = bucket_for(part)
-            enc = encode_packed(
-                part, self.normalizer,
-                include_static_perf=self.include_static_perf, spec=spec)
-            preds = np.asarray(self._predict(self.params, enc))
+        with span("repro.serve.pack") as sp:
+            packs = pack_graphs(graphs, self.node_budget,
+                                oversized="singleton")
+            parts = [[graphs[i] for i in pack] for pack in packs]
+            specs = [bucket_for(part) for part in parts]
+            sp.set_metadata(packs=len(packs))
+        for pack, part, spec in zip(packs, parts, specs):
+            nodes = sum(g.num_nodes for g in part)
+            with span("repro.serve.encode", graphs=len(part), nodes=nodes,
+                      node_capacity=spec.node_capacity):
+                enc = encode_packed(
+                    part, self.normalizer,
+                    include_static_perf=self.include_static_perf, spec=spec)
+            with span("repro.serve.predict",
+                      node_capacity=spec.node_capacity):
+                preds = np.asarray(self._predict(self.params, enc))
             out[pack] = preds[:len(pack)]
             use = self._bucket_use.setdefault(spec, [0, 0, 0.0])
             use[0] += 1
             use[1] += len(pack)
-            use[2] += sum(g.num_nodes for g in part) / spec.node_capacity
+            use[2] += nodes / spec.node_capacity
         return out
 
     def _score_segmented(self, graphs: Sequence[KernelGraph]) -> np.ndarray:
@@ -222,10 +231,15 @@ class CostModelService:
             g = graphs[i]
             if g.num_nodes <= self.node_budget:
                 continue
-            enc = encode_segmented(
-                [g], self.node_budget, self.normalizer,
-                include_static_perf=self.include_static_perf)
-            out[i] = float(np.asarray(self._predict(self.params, enc))[0])
+            with span("repro.serve.encode", graphs=1,
+                      nodes=g.num_nodes) as sp:
+                enc = encode_segmented(
+                    [g], self.node_budget, self.normalizer,
+                    include_static_perf=self.include_static_perf)
+                sp.set_metadata(node_capacity=enc.num_nodes)
+            with span("repro.serve.predict", node_capacity=enc.num_nodes):
+                out[i] = float(np.asarray(self._predict(self.params,
+                                                        enc))[0])
             use = self._bucket_use.setdefault("segmented", [0, 0, 0.0])
             use[0] += 1
             use[1] += 1
@@ -238,10 +252,16 @@ class CostModelService:
         for i in range(0, len(graphs), self.chunk):
             part = list(graphs[i:i + self.chunk])
             pad = self.chunk - len(part)
-            enc = F.encode_batch(
-                part + [part[-1]] * pad, self.max_nodes, self.normalizer,
-                include_static_perf=self.include_static_perf)
-            preds = np.asarray(self._predict(self.params, enc))
+            capacity = self.chunk * self.max_nodes
+            with span("repro.serve.encode", graphs=len(part),
+                      nodes=sum(g.num_nodes for g in part),
+                      node_capacity=capacity):
+                enc = F.encode_batch(
+                    part + [part[-1]] * pad, self.max_nodes,
+                    self.normalizer,
+                    include_static_perf=self.include_static_perf)
+            with span("repro.serve.predict", node_capacity=capacity):
+                preds = np.asarray(self._predict(self.params, enc))
             out.append(preds[:len(part)])
             use = self._bucket_use.setdefault(key, [0, 0, 0.0])
             use[0] += 1
@@ -265,11 +285,14 @@ class CostModelService:
             self._requests += 1
             self._graphs += len(graphs)
         entries: list[float | Ticket] = []
-        for g in graphs:
-            key = self.cache_key(g)
-            val = self.cache.get(key)
-            entries.append(self.coalescer.add(key, g)
-                           if val is None else val)
+        with span("repro.serve.lookup", graphs=len(graphs)) as sp:
+            for g in graphs:
+                key = self.cache_key(g)
+                val = self.cache.get(key)
+                entries.append(self.coalescer.add(key, g)
+                               if val is None else val)
+            sp.set_metadata(hits=sum(not isinstance(e, Ticket)
+                                     for e in entries))
         return PendingRequest(self, entries)
 
     def predict_many(self, graphs: Sequence[KernelGraph]) -> np.ndarray:
@@ -277,8 +300,15 @@ class CostModelService:
         point. Returns one float32 score per graph, in input order."""
         t0 = time.perf_counter()
         out = self.submit(graphs).result()
-        self._latencies_ms.append((time.perf_counter() - t0) * 1e3)
+        self.record_latency((time.perf_counter() - t0) * 1e3)
         return out
+
+    def record_latency(self, ms: float) -> None:
+        """Count one answered request's latency in `stats()`' p50/p99:
+        a `predict_many` call, or a server request from admission to
+        reply."""
+        with self._stats_lock:
+            self._latencies_ms.append(ms)
 
     def predict(self, graph: KernelGraph) -> float:
         return float(self.predict_many([graph])[0])
@@ -310,9 +340,9 @@ class CostModelService:
             spec: BucketStats(flushes=int(u[0]), graphs=int(u[1]),
                               mean_node_occupancy=u[2] / u[0])
             for spec, u in dict(self._bucket_use).items()}
-        lat = list(self._latencies_ms)
         with self._stats_lock:
             requests, graphs = self._requests, self._graphs
+            lat = list(self._latencies_ms)
         return ServiceStats(
             requests=requests, graphs=graphs,
             cache=self.cache.stats(), coalesced=self.coalescer.coalesced,

@@ -57,6 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.losses import log_mse_loss, mse_loss, pairwise_rank_loss
 from repro.core.model import CostModelConfig, cost_model_apply, cost_model_init
+from repro.spans import span
 from repro.training import checkpoint as ckpt_lib
 from repro.training.compression import compressed_allreduce, zeros_like_error
 from repro.training.optim import AdamWConfig, adamw_init, adamw_update
@@ -449,26 +450,27 @@ class CostModelTrainer:
         total = steps if steps is not None else cfg.steps
         if resume:
             self.maybe_resume()
-        self._install_signal_handlers()
-        sampler = self.sampler
-        if cfg.prefetch:
-            from repro.data.prefetch import Prefetcher
-            sampler = Prefetcher(self.sampler, depth=cfg.prefetch,
-                                 start_step=self.step,
-                                 device_put=cfg.prefetch_device_put)
-        try:
-            if self._use_mesh:
-                from repro.sharding.context import activation_sharding
-                mapping = {"dp": cfg.data_axis,
-                           "axis_sizes": {cfg.data_axis: cfg.dp,
-                                          "model": cfg.mp}}
-                with self.mesh, activation_sharding(mapping):
-                    return self._run_loop(sampler, total, eval_fn,
-                                          eval_every)
-            return self._run_loop(sampler, total, eval_fn, eval_every)
-        finally:
-            if sampler is not self.sampler:
-                sampler.close()
+        with span("repro.train.run", steps=max(0, total - self.step)):
+            self._install_signal_handlers()
+            sampler = self.sampler
+            if cfg.prefetch:
+                from repro.data.prefetch import Prefetcher
+                sampler = Prefetcher(self.sampler, depth=cfg.prefetch,
+                                     start_step=self.step,
+                                     device_put=cfg.prefetch_device_put)
+            try:
+                if self._use_mesh:
+                    from repro.sharding.context import activation_sharding
+                    mapping = {"dp": cfg.data_axis,
+                               "axis_sizes": {cfg.data_axis: cfg.dp,
+                                              "model": cfg.mp}}
+                    with self.mesh, activation_sharding(mapping):
+                        return self._run_loop(sampler, total, eval_fn,
+                                              eval_every)
+                return self._run_loop(sampler, total, eval_fn, eval_every)
+            finally:
+                if sampler is not self.sampler:
+                    sampler.close()
 
     def _step_rng(self, step: int):
         base = jax.random.key(self.cfg.seed + 1)
@@ -486,27 +488,35 @@ class CostModelTrainer:
         t0 = time.time()
         last_loss = float("nan")
         while self.step < total and not self._stop:
-            b = sampler.batch(self.step)
-            rng = self._step_rng(self.step)
-            group_ids = getattr(b, "group_ids",
-                                np.zeros_like(b.targets, np.int32))
-            self.params, self.opt_state, stats = self._train_step(
-                self.params, self.opt_state, b.graphs,
-                jnp.asarray(b.targets), jnp.asarray(group_ids),
-                jnp.asarray(b.valid), rng)
-            self.step += 1
-            if self.step % cfg.log_every == 0 or self.step == total:
-                last_loss = float(stats["loss"])
-                self._log({"step": self.step, "loss": last_loss,
-                           "lr": float(stats["lr"]),
-                           "grad_norm": float(stats["grad_norm"]),
-                           "wall": time.time() - t0})
-            if cfg.ckpt_every and self.step % cfg.ckpt_every == 0:
-                self.save()
-            if eval_fn and eval_every and self.step % eval_every == 0:
-                ev = eval_fn(self.params, self.step)
-                self._log({"step": self.step, **{f"eval/{k}": v
-                                                 for k, v in ev.items()}})
+            with span("repro.train.step", step=self.step):
+                with span("repro.train.batch") as sp:
+                    b = sampler.batch(self.step)
+                    sp.set_metadata(graphs=int(np.size(b.valid)))
+                with span("repro.train.inputs"):
+                    rng = self._step_rng(self.step)
+                    group_ids = getattr(b, "group_ids",
+                                        np.zeros_like(b.targets, np.int32))
+                    targets, group_ids, valid = (jnp.asarray(b.targets),
+                                                 jnp.asarray(group_ids),
+                                                 jnp.asarray(b.valid))
+                with span("repro.train.dispatch"):
+                    self.params, self.opt_state, stats = self._train_step(
+                        self.params, self.opt_state, b.graphs, targets,
+                        group_ids, valid, rng)
+                self.step += 1
+                if self.step % cfg.log_every == 0 or self.step == total:
+                    with span("repro.train.sync"):
+                        last_loss = float(stats["loss"])
+                        self._log({"step": self.step, "loss": last_loss,
+                                   "lr": float(stats["lr"]),
+                                   "grad_norm": float(stats["grad_norm"]),
+                                   "wall": time.time() - t0})
+                if cfg.ckpt_every and self.step % cfg.ckpt_every == 0:
+                    self.save()
+                if eval_fn and eval_every and self.step % eval_every == 0:
+                    ev = eval_fn(self.params, self.step)
+                    self._log({"step": self.step,
+                               **{f"eval/{k}": v for k, v in ev.items()}})
         self.save()
         if self._metrics_f:
             self._metrics_f.close()

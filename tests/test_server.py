@@ -543,3 +543,111 @@ def test_server_stats_to_dict_roundtrip():
     assert set(d) == {"connections", "requests", "completed",
                       "shed_overloaded", "shed_deadline", "worker_failures",
                       "faults_injected", "restored_entries"}
+
+
+# ---------------------------------------------------------------------------
+# Spans and per-request latency
+# ---------------------------------------------------------------------------
+def test_server_and_client_import_without_jax():
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = ("import sys; import repro.serving.server, repro.serving.client; "
+            "sys.exit('jax' in sys.modules)")
+    env = dict(os.environ, PYTHONPATH=src)
+    assert subprocess.run([sys.executable, "-c", code], env=env,
+                          timeout=120).returncode == 0
+
+
+class SpanLog:
+    """Stand-in for `repro.spans.span`: records every span, its metadata
+    and whether it closed."""
+
+    def __init__(self):
+        self.spans = []
+        self._lock = threading.Lock()
+
+    def __call__(self, name, **meta):
+        rec = _LoggedSpan(name, meta)
+        with self._lock:
+            self.spans.append(rec)
+        return rec
+
+    def named(self, name):
+        with self._lock:
+            return [s for s in self.spans if s.name == name]
+
+
+class _LoggedSpan:
+    def __init__(self, name, meta):
+        self.name, self.meta, self.closed = name, dict(meta), False
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.closed = True
+
+    def set_metadata(self, **meta):
+        self.meta.update(meta)
+
+
+def test_every_queue_wait_span_closes(monkeypatch):
+    """One request served, one shed at its deadline, one refused as
+    overloaded: each opens a queue-wait span at admission, and each span
+    closes."""
+    import repro.serving.server as server_mod
+    log = SpanLog()
+    monkeypatch.setattr(server_mod, "span", log)
+    stub = StubService(blocking=True)
+    server = _start(stub, max_queue=1, coalesce_limit=1)
+    host, port = server.address
+    outcome = {}
+
+    def call(tag, deadline_ms, seed):
+        try:
+            with CostModelClient(host, port, retries=0) as c:
+                outcome[tag] = c.predict_many(
+                    [random_kernel(5, seed=seed)], deadline_ms=deadline_ms)
+        except ClientError as e:
+            outcome[tag] = e
+
+    served = threading.Thread(target=call, args=("served", 60_000, 0))
+    served.start()
+    assert stub.started.wait(timeout=JOIN_S)        # the worker is busy
+    late = threading.Thread(target=call, args=("late", 1.0, 1))
+    late.start()
+    poll = threading.Event()
+    for _ in range(2000):                           # parked in the queue
+        if server._queue.qsize() >= 1:
+            break
+        poll.wait(0.005)
+    assert server._queue.qsize() >= 1
+    call("refused", 60_000, 2)                      # the queue is full
+    poll.wait(0.01)                                 # > the 1 ms deadline
+    stub.gate.set()
+    served.join(timeout=JOIN_S)
+    late.join(timeout=JOIN_S)
+    server.stop()
+    assert outcome["served"].shape == (1,)
+    assert isinstance(outcome["late"], DeadlineExceeded)
+    assert isinstance(outcome["refused"], Overloaded)
+    waits = log.named("repro.serve.queue_wait")
+    assert sorted(s.meta["seq"] for s in waits) == [1, 2, 3]
+    assert all(s.closed for s in waits)
+    assert len(log.named("repro.serve.decode")) == 3
+    assert all(s.closed for s in log.spans)
+
+
+def test_served_requests_move_latency_percentiles(world):
+    svc = _service(world)
+    assert svc.stats().latency_p50_ms == 0.0
+    server = _start(svc)
+    with CostModelClient(*server.address) as c:
+        c.predict_many(world["graphs"][:2], deadline_ms=60_000)
+        st = c.stats()
+    server.stop()
+    assert svc.stats().latency_p50_ms > 0.0
+    assert st["service"]["latency_p99_ms"] >= \
+        st["service"]["latency_p50_ms"] > 0.0

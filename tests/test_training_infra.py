@@ -352,3 +352,42 @@ def test_compile_cache_placement(tmp_path, from_env):
     assert used == configured == want
     if from_env:
         assert os.listdir(tmp_path)
+
+
+def test_trainer_spans_in_a_profile(tmp_path):
+    """A profile of three steps holds one `repro.train.step` span per step,
+    each with its batch, inputs and dispatch spans inside it on the same
+    thread, all inside one `repro.train.run`."""
+    import glob
+
+    from jax.profiler import ProfileData
+    mc, tc, sampler = _tiny_setup(tmp_path, steps=3)
+    tc.ckpt_dir = ""
+    tr = CostModelTrainer(mc, tc, sampler)
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        tr.run(resume=False)
+    path, = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.end_ns, dict(ev.stats))
+                   for ev in line.events
+                   if ev.name.startswith("repro.train.")]
+            if evs:
+                lines.append(evs)
+    assert len(lines) == 1
+    evs = lines[0]
+    steps = [e for e in evs if e[0] == "repro.train.step"]
+    assert sorted(e[3]["step"] for e in steps) == [0, 1, 2]
+    runs = [e for e in evs if e[0] == "repro.train.run"]
+    assert len(runs) == 1 and runs[0][3]["steps"] == 3
+    for _, s, e, _ in steps:
+        assert runs[0][1] <= s and e <= runs[0][2]
+        inside = sorted(n for n, cs, ce, _ in evs if s <= cs and ce <= e
+                        and n != "repro.train.step")
+        assert [n for n in inside if n != "repro.train.sync"] == [
+            "repro.train.batch", "repro.train.dispatch",
+            "repro.train.inputs"]
+    assert [e[3]["graphs"] for e in evs if e[0] == "repro.train.batch"] \
+        == [8, 8, 8]
