@@ -36,7 +36,15 @@ per-device forward/backward under `shard_map` with psum'd loss and grads
 — int8-compressed when `compress_grads` (which composes with sparse
 batches here: the *global* batch has the leading axis the legacy path
 lacked). ``dp=1`` is bit-identical to the legacy jit path — same batch
-stream, same rng fold, pmean over a size-1 axis is exact. Checkpoints are
+stream, same rng fold, pmean over a size-1 axis is exact.
+
+Every step function takes ``key(seed + 1)``, made once, and the step
+number as an ``np.int32`` argument, and folds the step's dropout keys
+from them inside the jit (`step_keys`): the ladder
+``fold_in(key(seed + 1), step*dp + d)`` for device d, or
+``fold_in(key(seed + 1), step)`` at dp=0. Threefry gives the same bits
+traced as eager, so no eager JAX op runs between the batch and the
+launch, and the keys are those a host fold would give. Checkpoints are
 written by process 0 only and restore onto any dp layout (error-feedback
 buffers, the one per-device-layout state, restart at zero across layouts).
 """
@@ -89,6 +97,18 @@ class TrainerConfig:
     prefetch: int = 0
     prefetch_device_put: bool = False     # also overlap host->device copies
     optim: AdamWConfig = field(default_factory=AdamWConfig)
+
+
+def step_keys(base, step, dp: int = 0):
+    """The dropout keys of train step `step` from ``base = key(seed + 1)``,
+    traceable in both: the ladder ``fold_in(base, step*dp + d)``, one key
+    for each device d of `dp` (a ``[dp]`` key array), or one key
+    ``fold_in(base, step)`` at dp=0. dp=1's key is dp=0's, so the mesh
+    step at dp=1 draws the legacy path's dropout masks."""
+    if dp == 0:
+        return jax.random.fold_in(base, step)
+    return jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+        base, step * dp + jnp.arange(dp))
 
 
 def make_mesh_1d(axis: str = "data") -> Mesh:
@@ -173,6 +193,9 @@ class CostModelTrainer:
                     lambda x: jnp.zeros((cfg.dp,) + x.shape, x.dtype), ef)
             self.opt_state["ef"] = ef
 
+        # an argument of every step, not a constant of its trace, so one
+        # executable (and one persistent-cache entry) serves every seed
+        self._rng_base = jax.random.key(cfg.seed + 1)
         self._train_step = self._build_train_step()
 
     # ------------------------------------------------------------------
@@ -209,9 +232,10 @@ class CostModelTrainer:
         if not cfg.compress_grads:
             @partial(jax.jit, donate_argnums=(0,))
             def train_step(params, opt_state, batch, targets, group_ids,
-                           valid, rng):
+                           valid, base, step):
                 loss, grads = jax.value_and_grad(self._loss_fn)(
-                    params, batch, targets, group_ids, valid, rng)
+                    params, batch, targets, group_ids, valid,
+                    step_keys(base, step))
                 new_params, new_opt, stats = adamw_update(
                     params, grads, opt_state, cfg.optim)
                 stats["loss"] = loss
@@ -223,8 +247,9 @@ class CostModelTrainer:
         axis = cfg.data_axis
 
         def shmap_step(params, opt_state, batch, targets, group_ids, valid,
-                       rng):
+                       base, step):
             ef = opt_state["ef"]
+            rng = step_keys(base, step)
 
             def local(params, batch, targets, group_ids, valid, ef):
                 loss, grads = jax.value_and_grad(self._loss_fn)(
@@ -299,7 +324,8 @@ class CostModelTrainer:
 
         @partial(jax.jit, donate_argnums=(0,))
         def mesh_step(params, opt_state, batch, targets, group_ids, valid,
-                      rngs):
+                      base, step):
+            rngs = step_keys(base, step, cfg.dp)
             batch = constrain_batch_tree(batch, leading=0)
             targets, group_ids, valid = constrain_batch_tree(
                 (targets, group_ids, valid), leading=0)
@@ -472,17 +498,6 @@ class CostModelTrainer:
                 if sampler is not self.sampler:
                     sampler.close()
 
-    def _step_rng(self, step: int):
-        base = jax.random.key(self.cfg.seed + 1)
-        if not self._use_mesh:
-            return jax.random.fold_in(base, step)
-        # one key per device, folded from the SAME ladder the legacy path
-        # climbs: device d of dp at step k folds in k*dp + d, so dp=1
-        # device 0 gets fold_in(base, k) — bit-identical to legacy
-        dp = self.cfg.dp
-        return jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
-            base, step * dp + jnp.arange(dp))
-
     def _run_loop(self, sampler, total: int, eval_fn, eval_every) -> dict:
         cfg = self.cfg
         t0 = time.time()
@@ -493,16 +508,15 @@ class CostModelTrainer:
                     b = sampler.batch(self.step)
                     sp.set_metadata(graphs=int(np.size(b.valid)))
                 with span("repro.train.inputs"):
-                    rng = self._step_rng(self.step)
                     group_ids = getattr(b, "group_ids",
                                         np.zeros_like(b.targets, np.int32))
-                    targets, group_ids, valid = (jnp.asarray(b.targets),
-                                                 jnp.asarray(group_ids),
-                                                 jnp.asarray(b.valid))
+                    # the jitted step folds its dropout keys from this
+                    # scalar (`step_keys`); a traced argument, not static
+                    step = np.int32(self.step)
                 with span("repro.train.dispatch"):
                     self.params, self.opt_state, stats = self._train_step(
-                        self.params, self.opt_state, b.graphs, targets,
-                        group_ids, valid, rng)
+                        self.params, self.opt_state, b.graphs, b.targets,
+                        group_ids, b.valid, self._rng_base, step)
                 self.step += 1
                 if self.step % cfg.log_every == 0 or self.step == total:
                     with span("repro.train.sync"):

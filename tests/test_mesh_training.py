@@ -11,6 +11,7 @@ subprocesses with forced device counts.
 import jax
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro.core.model import CostModelConfig
 from repro.core.simulator import TPUSimulator
@@ -21,8 +22,9 @@ from repro.data.sampler import (
 )
 from repro.data.synthetic import generate_program, random_kernel
 from repro.data.tile_dataset import build_tile_records, fit_tile_normalizer
-from repro.sharding.mesh import make_train_mesh
-from repro.training.trainer import CostModelTrainer, TrainerConfig
+from repro.sharding.context import shard_map_nocheck
+from repro.sharding.mesh import DATA_AXIS, make_train_mesh
+from repro.training.trainer import CostModelTrainer, TrainerConfig, step_keys
 
 needs_two = pytest.mark.skipif(
     jax.device_count() < 2,
@@ -51,8 +53,9 @@ def _trainer(tile_records, norm, dp, adjacency="sparse", **cfg_kw):
     mcfg = CostModelConfig(hidden_dim=16, gnn_layers=1,
                            transformer_layers=1, adjacency=adjacency)
     cfg_kw.setdefault("ckpt_every", 0)
-    cfg = TrainerConfig(task="tile", steps=3, log_every=100,
-                        seed=0, dp=dp, **cfg_kw)
+    cfg_kw.setdefault("seed", 0)
+    cfg = TrainerConfig(task="tile", steps=3, log_every=100, dp=dp,
+                        **cfg_kw)
     return CostModelTrainer(mcfg, cfg, _sampler(tile_records, norm,
                                                 adjacency))
 
@@ -188,6 +191,75 @@ def test_dp1_mesh_step_bit_identical_to_legacy(tile_records, norm):
         np.testing.assert_array_equal(a, b)
     for a, b in zip(_leaves(t0.opt_state), _leaves(t1.opt_state)):
         np.testing.assert_array_equal(a, b)
+
+
+# ------------------------------------------------------------ step keys
+@pytest.mark.parametrize("step", [0, 1, 37, 2**20])
+@pytest.mark.parametrize("dp", [0, 1, pytest.param(2, marks=needs_two)])
+def test_step_keys_in_jit_match_host_ladder(dp, step):
+    """The keys the jitted step folds from the step number are the host
+    ladder fold_in(key(seed + 1), step*dp + d) of DESIGN.md §13, byte for
+    byte, and under the mesh device d holds key d."""
+    base = jax.random.key(2**31 + 8)      # key(seed + 1), seed 2**31 + 7
+    data = jax.random.key_data
+    if dp == 0:
+        got = jax.jit(step_keys)(base, np.int32(step))
+        np.testing.assert_array_equal(
+            np.asarray(data(got)),
+            np.asarray(data(jax.random.fold_in(base, step))))
+        return
+    per_device = shard_map_nocheck(data, make_train_mesh(dp),
+                                   in_specs=P(DATA_AXIS),
+                                   out_specs=P(DATA_AXIS))
+    got = jax.jit(lambda k, s: per_device(step_keys(k, s, dp)))(
+        base, np.int32(step))
+    want = [np.asarray(data(jax.random.fold_in(base, step * dp + d)))
+            for d in range(dp)]
+    np.testing.assert_array_equal(np.asarray(got), np.stack(want))
+
+
+@pytest.mark.parametrize("dp", [0, 1])
+def test_step_number_adds_no_compile(tile_records, norm, dp):
+    """The step number is a traced argument of the train step: after a
+    warm-up run, eight more steps of the same bucket compile nothing."""
+    t = _trainer(tile_records, norm, dp=dp)
+    sampler = t.sampler
+
+    class Replay:
+        def batch(self, step):
+            return sampler.batch(0)
+
+    t.sampler = Replay()
+    t.run(steps=2, resume=False)
+    compiles = []
+
+    def on_duration(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        res = t.run(steps=10, resume=False)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert res["step"] == 10 and np.isfinite(res["loss"])
+    assert compiles == []
+
+
+@pytest.mark.parametrize("dp", [0, 1])
+def test_train_step_program_is_the_same_for_every_seed(tile_records, norm,
+                                                       dp):
+    """The seed reaches the train step as an argument, not as a constant
+    of its trace: trainers of two seeds lower the same program, so one
+    persistent-cache entry serves every seed."""
+    def program(seed):
+        t = _trainer(tile_records, norm, dp=dp, seed=seed)
+        b = t.sampler.batch(0)
+        return t._train_step.lower(
+            t.params, t.opt_state, b.graphs, b.targets, b.group_ids,
+            b.valid, t._rng_base, np.int32(0)).as_text()
+
+    assert program(0) == program(2**31 + 7)
 
 
 # --------------------------------------------------------- two devices
