@@ -183,6 +183,18 @@ def op_name(hlo: str) -> str:
     return f"{head} = {rest[:min(cut, 60)]}"
 
 
+# collective instructions (their async halves "-start" and "-done" too)
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+
+def is_collective(hlo: str) -> bool:
+    """Whether a device op's HLO text is a collective instruction, by its
+    name ("%all-reduce.3 = f32[8] all-reduce(%x)"); an operand so named
+    does not count."""
+    return hlo.partition(" = ")[0].lstrip("%").startswith(COLLECTIVES)
+
+
 def merge(intervals) -> list[tuple[float, float]]:
     """Union of (start, end) intervals, sorted and disjoint."""
     merged: list[list[float]] = []
@@ -207,7 +219,8 @@ def reduce_trace(trace: dict, *, top: int = 10) -> dict:
     length; device_ops: the `top` op names by summed device time (device
     0); idle_gaps: the idle time between busy intervals on device 0, split
     by name among the host spans that cover it, the rest "none"; module_s:
-    device seconds per executable name."""
+    device seconds per executable name; collective_s: the union of
+    device 0's collective ops (`is_collective`) inside the window."""
     windows = [(s, e) for name, s, e in trace["spans"]
                if name == WINDOW_SPAN]
     if not windows:
@@ -215,6 +228,7 @@ def reduce_trace(trace: dict, *, top: int = 10) -> dict:
     lo, hi = windows[-1]
     busy, ops, gaps, modules = [], defaultdict(float), defaultdict(float), \
         defaultdict(float)
+    collective = []
     spans = [(n, s, e) for n, s, e in trace["spans"] if n != WINDOW_SPAN]
     for i, plane in enumerate(sorted(trace["devices"])):
         dev = trace["devices"][plane]
@@ -227,6 +241,8 @@ def reduce_trace(trace: dict, *, top: int = 10) -> dict:
         for name, s, e in dev["ops"]:
             for cs, ce in _clip([(s, e)], lo, hi):
                 ops[op_name(name)] += (ce - cs) / 1e9
+                if is_collective(name):
+                    collective.append((cs, ce))
         for name, s, e in dev["modules"]:
             for cs, ce in _clip([(s, e)], lo, hi):
                 modules[name] += (ce - cs) / 1e9
@@ -248,7 +264,8 @@ def reduce_trace(trace: dict, *, top: int = 10) -> dict:
     return {"busy_s": (sum(busy) / len(busy) / 1e9) if busy else 0.0,
             "window_s": (hi - lo) / 1e9, "devices_busy": len(busy),
             "device_ops": rank(ops), "idle_gaps": rank(gaps),
-            "module_s": dict(modules)}
+            "module_s": dict(modules),
+            "collective_s": sum(e - s for s, e in merge(collective)) / 1e9}
 
 
 # ----------------------------------------------------------------------------

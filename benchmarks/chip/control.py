@@ -9,10 +9,11 @@ numbers. A sound check reads the control as not correct.
 
 It reads the same kind of sample as a run does, at the cell's own sizes:
 a serve cell's first requests of every client's stream, a train cell's
-first three steps. With `--program` a serve cell's sample is also scored
-by the program, so that one process reads the program's seeds and the
-control's. One JSON line per seed on standard output. The benchmark's runs
-never run it.
+first three steps over as many shards as the cell has chips. With
+`--program` the sample also goes through the program (a serve cell's
+service, a train cell's trainer on the cell's chips), so that one process
+reads the program's seeds and the control's. One JSON line per seed on
+standard output. The benchmark's runs never run it.
 """
 from __future__ import annotations
 
@@ -70,19 +71,34 @@ def serve_control(cfg: dict, mix: dict, seed: int,
     return out
 
 
-def train_control(cfg: dict, mix: dict, seed: int) -> dict:
+def train_control(cfg: dict, mix: dict, seed: int, dp: int = 1,
+                  program: bool = False) -> dict:
+    """The control's numbers on a train cell's first three steps over `dp`
+    shards; with `program`, the program's beside them: the cell's trainer
+    at dp over the first `dp` devices, driving the same three steps
+    through its own loop as a run does before its window."""
+    import jax
     import jax.numpy as jnp
 
     import traffic
     import train
-    gen = traffic.kind(mix)
-    base, norm = gen.corpus(cfg, mix, seed)
     opt = dict(mix["optim"])
+    if program:
+        gen, base, norm, _, trainer = train.build(cfg, mix, seed,
+                                                  jax.devices()[:dp], {})
+        prog = train.first_steps(trainer, cfg, seed, opt["b1"])
+        del trainer
+    else:
+        gen = traffic.kind(mix)
+        base, norm = gen.corpus(cfg, mix, seed)
     ref = train.reference_steps(cfg, norm, gen, base, seed, 3, jnp.float32,
-                                opt)
+                                opt, dp)
     low = train.reference_steps(cfg, norm, gen, base, seed, 3, jnp.bfloat16,
-                                opt)
-    return train.compare_steps(low, ref)
+                                opt, dp)
+    out = train.compare_steps(low, ref)
+    if program:
+        out["program"] = train.compare_steps(prog, ref)
+    return out
 
 
 def main(argv=None) -> int:
@@ -90,8 +106,8 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--program", action="store_true",
-                    help="a serve cell's program readings beside the "
-                         "control's, on the same sample")
+                    help="the program's readings beside the control's, "
+                         "on the same sample")
     args = ap.parse_args(argv)
     sys.path[:0] = [os.path.join(ROOT, "src"), HERE]
     import common
@@ -103,7 +119,7 @@ def main(argv=None) -> int:
         if traffic.kind(mix).LOOP == "serve":
             out = serve_control(cfg, mix, seed, args.program)
         else:
-            out = train_control(cfg, mix, seed)
+            out = train_control(cfg, mix, seed, wl["chips"], args.program)
         print(json.dumps(dict(out, workload=args.workload, seed=seed)),
               flush=True)
     return 0

@@ -380,12 +380,15 @@ def _lstm(p, xs, mask):
     return h
 
 
-def _encoder(p, x, mask, heads: int):
+def _encoder(p, x, mask, heads: int, attn_keep=None, rate: float = 0.0):
+    """Pre-norm encoder blocks; `attn_keep`, if given, holds one dropout
+    keep mask [B, N, D] per block, applied to the attention's output,
+    scaled by 1/keep."""
     import jax
     import jax.numpy as jnp
     bsz, n, d = x.shape
     hd = d // heads
-    for blk in p["blocks"]:
+    for i, blk in enumerate(p["blocks"]):
         y = _ln(blk["ln1"], x)
         q, k, v = (
             (y @ blk["attn"][name]["w"]).reshape(bsz, n, heads, hd)
@@ -395,22 +398,26 @@ def _encoder(p, x, mask, heads: int):
                            jnp.finfo(logits.dtype).min)
         att = jax.nn.softmax(logits, axis=-1)
         y = jnp.einsum("bhqk,bkhd->bqhd", att, v).reshape(bsz, n, d)
-        x = x + y @ blk["attn"]["o"]["w"]
+        y = y @ blk["attn"]["o"]["w"]
+        if attn_keep is not None:
+            y = jnp.where(attn_keep[i], y / (1.0 - rate), 0.0)
+        x = x + y
         y = _gelu(_ln(blk["ln2"], x) @ blk["fc1"]["w"] + blk["fc1"]["b"])
         x = x + y @ blk["fc2"]["w"] + blk["fc2"]["b"]
     return _ln(p["ln_f"], x)
 
 
-def forward(params, cfg: dict, b: dict, node_keep=None):
+def forward(params, cfg: dict, b: dict, node_keep=None, attn_keep=None):
     """Scores [B] of a dense batch (`dense_batch`), in the dtype of
-    `params` and `b`."""
+    `params` and `b`. The dropout keep masks, for training: `node_keep`
+    before the node-final MLP, `attn_keep` one per Transformer block."""
     h = node_embeddings(params, cfg, b, node_keep)
     red = params["reduction"]
     if cfg["reduction"] == "lstm":
         kappa = _lstm(red["lstm"], h, b["mask"])
     else:
         enc = _encoder(red["encoder"], h, b["mask"],
-                       cfg["transformer_heads"])
+                       cfg["transformer_heads"], attn_keep, cfg["dropout"])
         kappa = (enc * b["mask"][..., None]).sum(1)
     return (kappa @ params["head"]["w"])[:, 0]
 
@@ -472,8 +479,17 @@ def frozen(cfg: dict) -> dict:
 
 
 # ----------------------------------------------------------------------------
-# training (section 3.3): pairwise rank loss, global-norm clip, AdamW
+# training (section 3.3): log-MSE and pairwise rank losses, global-norm
+# clip, AdamW
 # ----------------------------------------------------------------------------
+def log_mse_loss(preds, targets, valid):
+    """Squared error of the predicted log runtime against the log of the
+    measured runtime (seconds), averaged over the valid graphs."""
+    import jax.numpy as jnp
+    err = (preds - jnp.log(targets + 1e-12)) ** 2
+    return (err * valid).sum() / jnp.maximum(valid.sum(), 1.0)
+
+
 def rank_loss(preds, targets, groups, valid):
     """Pairwise hinge rank loss, Eq. (1): pairs of one kernel (group), both
     valid, where the first is truly slower; over n(n-1)/2."""

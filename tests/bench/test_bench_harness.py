@@ -14,6 +14,7 @@ import time
 import numpy as np
 import pytest
 
+import faults
 import tiny
 
 import run
@@ -144,21 +145,44 @@ def test_a_sound_run_is_correct(root, workload):
 
 
 @pytest.mark.parametrize("workload", ["serve-tile-search",
-                                      "serve-whole-program", "train-tile"])
+                                      "serve-whole-program", "train-tile",
+                                      "train-fusion-dp4"])
 def test_the_control_fails_a_check(root, workload):
     """The control reads above a limit; the program, on the same sample,
-    within every limit."""
+    within every limit (a train cell's program where it runs on one
+    device)."""
     import control
     import traffic
-    _, _, cfg, mix = run.load_cell(root, workload)
+    _, wl, cfg, mix = run.load_cell(root, workload)
     if traffic.kind(mix).LOOP == "serve":
         got = control.serve_control(cfg, mix, 7, program=True)
         assert got["score_gap_p95"] > mix["limit_score_gap_p95"]
         for k, v in got["program"].items():
             assert v <= mix["limit_" + k], got
     else:
-        got = control.train_control(cfg, mix, 7)
+        one = wl["chips"] == 1
+        got = control.train_control(cfg, mix, 7, wl["chips"], program=one)
         assert any(got[k] > v for k, v in mix["limits"].items()), got
+        for k, v in mix["limits"].items():
+            assert not one or got["program"][k] <= v, got
+
+
+@pytest.mark.parametrize("workload", ["train-tile", "train-fusion-dp4"])
+def test_the_census_bucket_is_the_timed_batch_shape(root, workload):
+    """The bucket the census warms for a step is the shape of the timed
+    sampler's batch of that step, at the cell's number of shards: where
+    the program's rule for the shared bucket moves, this fails before a
+    window compiles."""
+    import traffic
+    import train
+    _, wl, cfg, mix = run.load_cell(root, workload)
+    base, _ = traffic.kind(mix).corpus(cfg, mix, 5)
+    timed = train._timed_sampler_class().for_mesh(base, wl["chips"])
+    for step in range(0, 400, 40):
+        g = timed.batch(step).graphs
+        assert train.step_bucket(timed, step) == (
+            g.opcodes.shape[1], g.edge_src.shape[1],
+            g.kernel_feats.shape[1], g.gather_idx.shape[2]), step
 
 
 def _alter_answer(out, batch):
@@ -186,32 +210,9 @@ def test_altered_answers_are_not_correct(root, workload, alter):
             out["checks"]["score_gap_max"]["limit"], out["checks"]
 
 
-def _unchanged(step):
-    def faulty(params, opt, *args):
-        import jax
-        import jax.numpy as jnp
-        _, _, stats = step(jax.tree_util.tree_map(jnp.copy, params), opt,
-                           *args)
-        return params, opt, stats
-    return faulty
-
-
-def _altered_loss(step):
-    def faulty(*args):
-        params, opt, stats = step(*args)
-        return params, opt, dict(stats, loss=stats["loss"] * 1.05)
-    return faulty
-
-
-def _half_batch(b):
-    valid = np.array(b.valid, copy=True)
-    valid[..., valid.shape[-1] // 2:] = 0.0
-    b.valid = valid
-    return b
-
-
 @pytest.mark.parametrize("fault", [
-    {"step": _unchanged}, {"batch": _half_batch}, {"step": _altered_loss}],
+    {"step": faults.unchanged}, {"batch": faults.half_batch},
+    {"step": faults.altered_loss}],
     ids=["state-unchanged", "half-batch", "altered-loss"])
 def test_a_training_fault_is_not_correct(root, fault):
     out = _run(root, "train-tile", fault=fault)

@@ -78,6 +78,44 @@ def test_reduce_trace_without_device_ops_reads_no_busy_time():
     trace = {"devices": {}, "spans": _ms((common.WINDOW_SPAN, 0, 10))}
     r = common.reduce_trace(trace)
     assert r["devices_busy"] == 0 and r["busy_s"] == 0.0
+    assert r["collective_s"] == 0.0
+
+
+def test_reduce_trace_sums_device_0s_collectives_in_the_window():
+    """All-reduce time on device 0 alone, overlaps counted once, clipped to
+    the window; a fusion that only takes an all-reduce's result is no
+    collective, nor is device 1's all-reduce."""
+    ar = "%all-reduce.3 = f32[192,768]{1,0} all-reduce(f32[192,768] %x)"
+    trace = {
+        "devices": {
+            "/device:TPU:0": {"ops": _ms(
+                ("%fusion.1 = f32[8] fusion(%all-reduce.3)", 0, 5),
+                (ar, 10, 14),
+                ("%all-reduce-start.1 = f32[8] all-reduce-start(%y)", 20,
+                 21),
+                ("%all-reduce-done.1 = f32[8] all-reduce-done(%s)", 20.5,
+                 23),
+                (ar, 98, 104)), "modules": []},
+            "/device:TPU:1": {"ops": _ms((ar, 30, 90)), "modules": []}},
+        "spans": _ms((common.WINDOW_SPAN, 0, 100))}
+    r = common.reduce_trace(trace)
+    assert r["collective_s"] == pytest.approx(0.004 + 0.003 + 0.002)
+    assert r["devices_busy"] == 2
+
+
+@pytest.mark.parametrize("hlo,want", [
+    ("%all-reduce.3 = f32[8]{0} all-reduce(f32[8]{0} %x), to_apply=%add",
+     True),
+    ("%all-reduce-start.1 = (f32[8], f32[8]) all-reduce-start(%x, %y)",
+     True),
+    ("%all-gather.2 = f32[32] all-gather(%x), dimensions={0}", True),
+    ("%reduce-scatter.1 = f32[2] reduce-scatter(%x)", True),
+    ("all-reduce.7", True),
+    ("%fusion.1 = f32[8] fusion(%all-reduce.3, %y)", False),
+    ("%copy.1 = f32[8] copy(%x)", False),
+    ("%reduce.4 = f32[] reduce(%x, %c)", False)])
+def test_is_collective_reads_the_instruction_not_its_operands(hlo, want):
+    assert common.is_collective(hlo) is want
 
 
 def test_load_trace_finds_the_window_span_in_a_recorded_trace(tmp_path):
