@@ -10,6 +10,15 @@ how the 10 assigned architectures join the cost-model corpus (paper §4's
 Control-flow primitives (scan/while/cond) are inlined one body iteration
 deep — matching how the cost model sees kernels (XLA kernels never span
 loop boundaries).
+
+`import_arch_program(name)` imports an architecture two ways, chosen by
+the name: `<arch>` traces its smoke config (d_model 64, float32, each
+layer stack's scan inlined once, programs cut at 4 096 nodes), and
+`<arch>:published` traces its published config abstractly — parameters
+from `jax.eval_shape`, inputs as `ShapeDtypeStruct`s, so no weight is
+ever allocated — at one 4 096-token sequence, with every layer stack
+unrolled to its repeats and no node cut. `<arch>:published:<n>` keeps the
+first n layers, taking whole repeats off the last stack.
 """
 from __future__ import annotations
 
@@ -22,6 +31,10 @@ from repro.core import opset
 from repro.core.graph import KernelGraph, Node
 
 _MAX_NODES_PER_PROGRAM = 4096
+PUBLISHED = ":published"
+# the published trace's one sequence: DeepSeek-V3's pre-training length
+# (arXiv:2412.19437 §4.2), and every registry model's train_4k length
+PUBLISHED_SEQ = 4096
 
 
 def _dtype_bytes(aval) -> int:
@@ -37,10 +50,7 @@ def _shape(aval) -> tuple[int, ...]:
 
 
 def _op_for(eqn) -> opset.OpInfo:
-    name = eqn.primitive.name
-    if name == "reduce_sum" or name in opset.JAX_PRIMITIVE_MAP:
-        return opset.JAX_PRIMITIVE_MAP.get(name, opset.CUSTOM_CALL)
-    return opset.JAX_PRIMITIVE_MAP.get(name, opset.CUSTOM_CALL)
+    return opset.JAX_PRIMITIVE_MAP.get(eqn.primitive.name, opset.CUSTOM_CALL)
 
 
 def _contract_dim(eqn) -> int:
@@ -74,8 +84,11 @@ def _reduced_dims(eqn) -> tuple[int, ...]:
     return ()
 
 
-def jaxpr_to_program(closed_jaxpr, name: str, program: str) -> KernelGraph:
-    """Flatten a ClosedJaxpr (inlining inner jaxprs once) to a program."""
+def jaxpr_to_program(closed_jaxpr, name: str, program: str, *,
+                     max_nodes: int | None = _MAX_NODES_PER_PROGRAM
+                     ) -> KernelGraph:
+    """Flatten a ClosedJaxpr (inlining inner jaxprs once) to a program of
+    at most `max_nodes` nodes (None: no cut)."""
     nodes: list[Node] = []
     var_to_node: dict = {}
 
@@ -97,7 +110,7 @@ def jaxpr_to_program(closed_jaxpr, name: str, program: str) -> KernelGraph:
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            if len(nodes) >= _MAX_NODES_PER_PROGRAM:
+            if max_nodes is not None and len(nodes) >= max_nodes:
                 return
             prim = eqn.primitive.name
             inner = None
@@ -158,20 +171,30 @@ def jaxpr_to_program(closed_jaxpr, name: str, program: str) -> KernelGraph:
 
 
 def import_jaxpr(fn, *args, name: str = "imported",
-                 program: str | None = None) -> KernelGraph:
+                 program: str | None = None,
+                 max_nodes: int | None = _MAX_NODES_PER_PROGRAM
+                 ) -> KernelGraph:
     closed = jax.make_jaxpr(fn)(*args)
-    return jaxpr_to_program(closed, name, program or name)
+    return jaxpr_to_program(closed, name, program or name,
+                            max_nodes=max_nodes)
 
 
 def import_arch_program(arch: str, seq: int = 64, batch: int = 2
                         ) -> KernelGraph:
-    """Trace one smoke-scale forward pass of an assigned architecture into
-    a cost-model program (corpus entry `arch_<name>`)."""
+    """Trace one forward pass and loss of an assigned architecture into a
+    cost-model program (corpus entry `arch_<name>`).
+
+    `arch` is a registry name, traced at its smoke config with concrete
+    weights at `seq` x `batch` tokens; or `<name>:published[:<layers>]`,
+    traced at its published config abstractly (`_published_program`)."""
     from repro.models import registry
     from repro.models import lm
     from repro.models.config import ShapeSpec
     from repro.models.inputs import make_batch
 
+    name, published, layers = arch.partition(PUBLISHED)
+    if published:
+        return _published_program(name, int(layers.lstrip(":") or 0))
     cfg = registry.get_smoke_config(arch)
     shape = ShapeSpec("import", seq, batch, "train")
     batch_data = make_batch(cfg, shape)
@@ -182,3 +205,40 @@ def import_arch_program(arch: str, seq: int = 64, batch: int = 2
 
     return import_jaxpr(fwd, params, batch_data,
                         name=f"arch_{arch}", program=f"arch_{arch}")
+
+
+def _published_program(arch: str, layers: int = 0) -> KernelGraph:
+    """The forward pass and loss of `arch`'s published config over one
+    `PUBLISHED_SEQ`-token sequence, traced from abstract parameters and
+    inputs (nothing allocated), each layer stack unrolled to its repeats
+    (`scan_layers=False`: the model slices every layer's weights out of
+    its stack, as the scanned program does each iteration), uncut. With
+    `layers`, the first `layers` layers only: whole repeats of the last
+    stack's pattern come off, every width stays."""
+    from dataclasses import replace
+
+    from repro.models import registry
+    from repro.models import lm
+    from repro.models.config import ShapeSpec
+    from repro.models.inputs import input_specs
+
+    cfg = replace(registry.get_config(arch), scan_layers=False)
+    label = f"arch_{arch}{PUBLISHED}"
+    if layers and layers != cfg.num_layers:
+        *head, last = cfg.stacks
+        cut, rest = divmod(cfg.num_layers - layers, len(last.pattern))
+        if rest or not 0 < cut < last.repeats:
+            raise ValueError(f"{arch}: {layers} layers is not the "
+                             f"published {cfg.num_layers} less whole "
+                             f"repeats of its last stack")
+        cfg = replace(cfg, stacks=(*head, replace(
+            last, repeats=last.repeats - cut)))
+        label += f":{layers}"
+    params = lm.init_abstract(cfg)
+    batch = input_specs(cfg, ShapeSpec("import", PUBLISHED_SEQ, 1, "train"))
+
+    def fwd(params, batch_data):
+        return lm.loss_fn(params, cfg, batch_data)
+
+    return import_jaxpr(fwd, params, batch, name=label, program=label,
+                        max_nodes=None)

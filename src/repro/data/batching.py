@@ -180,7 +180,8 @@ def iter_packed_batches(graphs: Sequence[KernelGraph], node_budget: int,
 
 def encode_segmented(graphs: Sequence[KernelGraph], node_budget: int,
                      normalizer: FeatureNormalizer | None = None,
-                     *, include_static_perf: bool = True
+                     *, include_static_perf: bool = True,
+                     segmentations: Sequence | None = None
                      ) -> "F.SegmentedGraphBatch":
     """Encode whole-program graphs of *any* size into one
     `features.SegmentedGraphBatch` (DESIGN.md §12).
@@ -191,7 +192,8 @@ def encode_segmented(graphs: Sequence[KernelGraph], node_budget: int,
     ladder, and the outer arrays reassemble owned-node embeddings into
     whole-graph node order for the readout. Graphs that fit the budget
     take the identity path: their inner slots are bit-identical to
-    `encode_packed(graphs)` on the same list.
+    `encode_packed(graphs)` on the same list. `segmentations`, if given,
+    are the graphs' `segment_graph(g, node_budget)`, already made.
 
     >>> from repro.data.synthetic import random_kernel
     >>> gs = [random_kernel(40, seed=0), random_kernel(7, seed=1)]
@@ -205,7 +207,8 @@ def encode_segmented(graphs: Sequence[KernelGraph], node_budget: int,
 
     if not graphs:
         raise ValueError("empty graph list")
-    segs = [segment_graph(g, node_budget) for g in graphs]
+    segs = (list(segmentations) if segmentations is not None
+            else [segment_graph(g, node_budget) for g in graphs])
     parts = [s.graph for sg in segs for s in sg.segments]
     inner = encode_packed(parts, normalizer,
                           include_static_perf=include_static_perf)

@@ -22,6 +22,13 @@ class MoEConfig:
     d_ff_shared: int = 0
     capacity_factor: float = 1.25
     router_scale: bool = False            # deepseek sigmoid+bias routing
+    # DeepSeek-V3 group-limited routing (HF `topk_method` noaux_tc): the
+    # experts fall into `n_group` groups, a token keeps the `topk_group`
+    # groups whose two best biased scores sum highest and picks its top_k
+    # experts inside them; 1 group is plain top-k
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0    # gates x this after normalizing
 
 
 @dataclass(frozen=True)

@@ -309,19 +309,39 @@ def moe_init(rng, cfg: ModelConfig) -> dict:
 
 
 def _route(params: dict, mc: MoEConfig, xf: jnp.ndarray):
-    """xf: [T, D] -> (gates [T,K], ids [T,K])."""
+    """xf: [T, D] -> (gates [T,K], ids [T,K]).
+
+    Softmax top-k, or with `router_scale` DeepSeek's sigmoid scores,
+    selected with the load-balancing bias added and weighted without it;
+    with `n_group` > 1 the selection is group-limited (noaux_tc)."""
     logits = (xf.astype(jnp.float32) @ params["router"])
     if mc.router_scale:
         scores = jax.nn.sigmoid(logits)
         sel = scores + params["e_bias"][None, :]
+        if mc.n_group > 1:
+            sel = _group_limited(sel, mc.n_group, mc.topk_group)
         _, ids = jax.lax.top_k(sel, mc.top_k)
         gates = jnp.take_along_axis(scores, ids, axis=-1)
-        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
         gates, ids = jax.lax.top_k(probs, mc.top_k)
-        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    if mc.routed_scaling_factor != 1.0:
+        gates = gates * mc.routed_scaling_factor
     return gates, ids
+
+
+def _group_limited(sel: jnp.ndarray, n_group: int,
+                   topk_group: int) -> jnp.ndarray:
+    """sel [T, E] with every expert outside a token's `topk_group` best
+    groups set to -inf; a group scores the sum of its two best experts."""
+    T, E = sel.shape
+    grouped = sel.reshape(T, n_group, E // n_group)
+    group_scores = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+    _, keep = jax.lax.top_k(group_scores, topk_group)          # [T, topk_g]
+    kept = jnp.zeros((T, n_group), jnp.bool_).at[
+        jnp.arange(T)[:, None], keep].set(True)
+    return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(T, E)
 
 
 def moe_apply(params: dict, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:

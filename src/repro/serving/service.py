@@ -81,6 +81,13 @@ class ServiceStats:
     buckets: dict[BucketSpec | str, BucketStats] = field(default_factory=dict)
     latency_p50_ms: float = 0.0   # per request (`record_latency`)
     latency_p99_ms: float = 0.0
+    # the segmented backend's graphs above the node budget: how many, the
+    # segments they were cut into, their nodes (each owned by one
+    # segment) and the halo copies the segments carry besides
+    segmented_graphs: int = 0
+    segments: int = 0
+    owned_nodes: int = 0
+    halo_nodes: int = 0
 
     @property
     def hit_rate(self) -> float:
@@ -175,6 +182,8 @@ class CostModelService:
                                           node_budget=self.node_budget,
                                           on_scored=self.cache.put)
         self._bucket_use: dict[BucketSpec | str, list[float]] = {}
+        # [graphs, segments, owned nodes, halo nodes] segmented so far
+        self._segmented = [0, 0, 0, 0]
         # cache and coalescer are internally locked; this lock only guards
         # the service-level counters, so submit() is safe from any thread
         # (the socket server's connection threads + scoring worker)
@@ -221,6 +230,7 @@ class CostModelService:
         segmented into ≤ node_budget blocks and reassembled before readout,
         one giant graph per device batch."""
         from repro.data.batching import encode_segmented
+        from repro.data.segmentation import segment_graph
         out = np.zeros((len(graphs),), np.float32)
         small = [i for i, g in enumerate(graphs)
                  if g.num_nodes <= self.node_budget]
@@ -233,9 +243,14 @@ class CostModelService:
                 continue
             with span("repro.serve.encode", graphs=1,
                       nodes=g.num_nodes) as sp:
+                with span("repro.serve.segment", nodes=g.num_nodes) as ssp:
+                    seg = segment_graph(g, self.node_budget)
+                    ssp.set_metadata(segments=seg.num_segments,
+                                     halo=seg.total_halo)
                 enc = encode_segmented(
                     [g], self.node_budget, self.normalizer,
-                    include_static_perf=self.include_static_perf)
+                    include_static_perf=self.include_static_perf,
+                    segmentations=[seg])
                 sp.set_metadata(node_capacity=enc.num_nodes)
             with span("repro.serve.predict", node_capacity=enc.num_nodes):
                 out[i] = float(np.asarray(self._predict(self.params,
@@ -244,6 +259,10 @@ class CostModelService:
             use[0] += 1
             use[1] += 1
             use[2] += g.num_nodes / enc.num_nodes
+            with self._stats_lock:
+                for j, n in enumerate((1, seg.num_segments, g.num_nodes,
+                                       seg.total_halo)):
+                    self._segmented[j] += n
         return out
 
     def _score_dense(self, graphs: Sequence[KernelGraph]) -> np.ndarray:
@@ -343,13 +362,16 @@ class CostModelService:
         with self._stats_lock:
             requests, graphs = self._requests, self._graphs
             lat = list(self._latencies_ms)
+            seg_graphs, segments, owned, halo = self._segmented
         return ServiceStats(
             requests=requests, graphs=graphs,
             cache=self.cache.stats(), coalesced=self.coalescer.coalesced,
             flushes=self.coalescer.flushes,
             flush_sizes=tuple(self.coalescer.flush_sizes), buckets=buckets,
             latency_p50_ms=_percentile(lat, 50),
-            latency_p99_ms=_percentile(lat, 99))
+            latency_p99_ms=_percentile(lat, 99),
+            segmented_graphs=seg_graphs, segments=segments,
+            owned_nodes=owned, halo_nodes=halo)
 
     # --- drop-in scorers for the existing call sites -----------------------
     def tile_scorer(self) -> Callable:
