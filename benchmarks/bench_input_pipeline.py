@@ -6,10 +6,10 @@ this pipeline the sampler re-ran full feature extraction per config with a
 per-node Python loop. This bench replays the same deterministic batch
 stream two ways:
 
-  * old — `node_features_reference` (per-node loop) + `EncodeCache(0)`
-    (every draw encodes fresh) + synchronous encode in the train loop: the
-    pre-cache behavior of every call site.
-  * new — vectorized `node_features` + the shared structural `EncodeCache`
+  * old — the per-node oracles (`features._build_encoded_reference`) +
+    `EncodeCache(0)` (every draw encodes fresh) + synchronous encode in the
+    train loop: the pre-cache behavior of every call site.
+  * new — the columnar encode + the shared structural `EncodeCache`
     (tile variants rewrite only `TILE_SLICE`) + `TrainerConfig.prefetch`
     encode-ahead.
 
@@ -61,20 +61,20 @@ ENCODE_STEPS = max(int(40 * SCALE), 15)
 TRAIN_WARM = 3
 TRAIN_STEPS = max(int(30 * SCALE), 12)
 
-_VECTORIZED_NODE_FEATURES = F.node_features
+_COLUMNAR_ENCODE = F._build_encoded
 
 
 @contextmanager
 def encoder(mode: str):
-    """'old' = reference per-node-loop encoder, caching disabled;
-    'new' = vectorized encoder + a fresh EncodeCache."""
-    F.node_features = (F.node_features_reference if mode == "old"
-                       else _VECTORIZED_NODE_FEATURES)
+    """'old' = the per-node oracles, caching disabled;
+    'new' = the columnar encoder + a fresh EncodeCache."""
+    F._build_encoded = (F._build_encoded_reference if mode == "old"
+                        else _COLUMNAR_ENCODE)
     prev = F.set_encode_cache(F.EncodeCache(0 if mode == "old" else 4096))
     try:
         yield
     finally:
-        F.node_features = _VECTORIZED_NODE_FEATURES
+        F._build_encoded = _COLUMNAR_ENCODE
         F.set_encode_cache(prev)
 
 

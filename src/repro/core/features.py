@@ -19,6 +19,8 @@ import threading
 import weakref
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from itertools import chain
+from operator import attrgetter
 from typing import Sequence
 
 import numpy as np
@@ -50,29 +52,32 @@ def _subvec_rows(seqs: Sequence[Sequence[int]], k: int) -> np.ndarray:
     allocations. Bit-identical to stacking `_subvec(s, k)` per row (the
     values are small integers, exact in f64 regardless of reduction
     order)."""
-    n = len(seqs)
-    out = np.zeros((n, k + 3), np.float64)
-    if n == 0:
-        return out
-    lens = np.fromiter((len(s) for s in seqs), np.int64, count=n)
-    L = int(lens.max())
-    if L == 0:
-        return out
-    total = int(lens.sum())
-    flat = np.fromiter((float(x) for s in seqs for x in s), np.float64,
-                       count=total)
-    vals = np.zeros((n, L), np.float64)
-    row = np.repeat(np.arange(n), lens)
-    starts = np.zeros((n,), np.int64)
-    np.cumsum(lens[:-1], out=starts[1:])
-    col = np.arange(total) - np.repeat(starts, lens)
-    vals[row, col] = flat
-    out[:, :min(L, k)] = vals[:, :k]
-    out[:, k] = vals.sum(axis=1)
-    mask = np.arange(L)[None, :] < lens[:, None]
-    out[:, k + 1] = np.where(lens > 0,
-                             np.where(mask, vals, 1.0).prod(axis=1), 0.0)
-    out[:, k + 2] = np.log1p(out[:, k + 1])
+    return _subvec_cols(*_padded(seqs, np.float64), k)
+
+
+def _padded(seqs: Sequence[Sequence[int]], dtype) -> tuple:
+    """Ragged rows as ([len(seqs), longest] `dtype` values, zero past each
+    row's end; the mask of real entries; each row's length)."""
+    lens = np.array(list(map(len, seqs)), np.int64)
+    width = int(lens.max()) if len(seqs) else 0
+    mask = np.arange(width) < lens[:, None]
+    vals = np.zeros((len(seqs), width), dtype)
+    vals[mask] = list(chain.from_iterable(seqs))
+    return vals, mask, lens
+
+
+def _subvec_cols(vals: np.ndarray, mask: np.ndarray, lens: np.ndarray,
+                 k: int) -> np.ndarray:
+    """`_subvec_rows` of `_padded` rows."""
+    width = vals.shape[1]
+    out = np.zeros((len(vals), k + 3), np.float64)
+    if width:
+        vals = vals.astype(np.float64, copy=False)
+        out[:, :min(width, k)] = vals[:, :k]
+        out[:, k] = vals.sum(axis=1)
+        out[:, k + 1] = np.where(lens > 0,
+                                 np.where(mask, vals, 1.0).prod(axis=1), 0.0)
+        out[:, k + 2] = np.log1p(out[:, k + 1])
     return out
 
 
@@ -93,42 +98,141 @@ STATIC_PERF_SLICE = slice(TILE_FEATS, TILE_FEATS + 4)
 TILE_SLICE = slice(0, TILE_FEATS)
 
 
-def node_features(g: KernelGraph) -> np.ndarray:
-    """Per-node scalar features, vectorized over nodes: one Python pass
-    collects the scalars, then whole columns are written at once — no
-    per-node `np.concatenate`/`np.array` churn. Matches
-    `node_features_reference` bit for bit."""
+# per-opcode columns, indexed by `OpInfo.index`: FLOPs per element, and
+# 0/1 flags for no FLOPs (mem/none units), elementwise, transcendental,
+# read from HBM (parameter/constant), parameter
+_OP_COLUMNS = np.array([
+    (o.flops_per_elem, o.unit in ("mem", "none"), o.elementwise,
+     o.transcendental, o in (opset.PARAMETER, opset.CONSTANT),
+     o is opset.PARAMETER) for o in opset.OPCODES], np.float64)
+# int64 volume, byte and reduced-volume products are exact below this
+_EXACT_BOUND = 2.0 ** 62
+# the node fields `_columnar` gathers, in its order
+_NODE_FIELDS = tuple(map(attrgetter, (
+    "op.index", "dtype_bytes", "is_output", "contract_dim", "shape",
+    "reduced_dims", "inputs")))
+
+
+def _depth(inputs: list) -> int:
+    """`KernelGraph.depth` from the nodes' input tuples (topological)."""
+    dep = [0] * len(inputs)
+    for i, ins in enumerate(inputs):
+        m = 0
+        for j in ins:
+            if dep[j] > m:
+                m = dep[j]
+        dep[i] = m + 1
+    return max(dep, default=0)
+
+
+def _columnar(g: KernelGraph, *, with_nodes: bool = True):
+    """The structural encoding of `g` from its nodes' fields, gathered once
+    into columns, the rest in NumPy: (opcodes, node features, kernel
+    feature base with `TILE_SLICE` zero, unique edges). Without
+    `with_nodes` the node features and edges are None. None where a
+    shape, volume, byte count or reduced volume could pass int64: the
+    per-node oracles hold there.
+
+    Every value equals the oracles' bit for bit: volumes are exact int64
+    products converted once to f64 (as `float(int)`); each op class keeps
+    `Node.flops`' order of operations; the four static sums stay
+    sequential Python sums in node order (`np.sum` adds pairwise); the
+    edges keep `unique_edges`' first-occurrence order.
+    """
     nodes = g.nodes
-    n_nodes = g.num_nodes
-    feats = np.empty((n_nodes, NODE_FEATURE_DIM), np.float64)
-    feats[:, :SHAPE_FEATS] = _subvec_rows([n.shape for n in nodes],
-                                          SHAPE_SUBVEC)
+    n = len(nodes)
+    ops, dtype_bytes, outputs, contract, shapes, reduced, inputs = (
+        list(map(get, nodes)) for get in _NODE_FIELDS)
+    try:
+        dtype_bytes = np.array(dtype_bytes, np.int64)
+        contract = np.array(contract, np.int64)
+        shape, shape_mask, rank = _padded(shapes, np.int64)
+        red, red_mask, red_len = _padded(reduced, np.int64)
+    except OverflowError:
+        return None
+    ops = np.array(ops, np.int32)
+    outputs = np.array(outputs, bool)
+    shape_sub = _subvec_cols(shape, shape_mask, rank, SHAPE_SUBVEC)
+    red_pos = np.maximum(red, 1)           # padding reads 1 too
+    if n and float(np.max(shape_sub[:, SHAPE_SUBVEC + 1] * np.maximum(
+            red_pos.prod(axis=1, dtype=np.float64), dtype_bytes))
+            ) >= _EXACT_BOUND:
+        return None
+    volume_i = np.where(shape_mask, shape, 1).prod(axis=1)
+    volume = volume_i.astype(np.float64)
+    bytes_i = volume_i * dtype_bytes
+    op_cols = _OP_COLUMNS[ops]
+    transcendental = op_cols[:, 3]
+
+    # Node.flops, class by class: DOT, CONV, mem/none, then the rest with
+    # reduced dims folded into the input volume
+    flops = np.where(
+        ops == opset.DOT.index, 2.0 * volume * np.maximum(contract, 1),
+        op_cols[:, 0] * (volume_i * red_pos.prod(axis=1)).astype(np.float64)
+        * (1.0 - op_cols[:, 1]))
+    conv = np.flatnonzero(ops == opset.CONV.index)
+    filters = [nodes[i].filter_size for i in conv.tolist()]
+    if filters:
+        kh, kw = np.array(filters, np.int64).T
+        flops[conv] = (2.0 * volume[conv] * np.maximum(contract[conv], 1)
+                       * np.maximum(kh, 1) * np.maximum(kw, 1))
+
+    written = bytes_i[outputs] if outputs.any() else bytes_i[-1:]
+    static = np.log1p([
+        float(sum(flops.tolist())),
+        float(sum(bytes_i[op_cols[:, 4] > 0].tolist())),
+        float(sum(written.tolist())),
+        float(sum((volume * transcendental).tolist())),
+    ])
+    kernel_base = np.zeros((KERNEL_FEATURE_DIM,), np.float64)
+    kernel_base[STATIC_PERF_SLICE] = static
+    kernel_base[-2:] = n, _depth(inputs)
+    if not with_nodes:
+        return ops, None, kernel_base, None
+
+    src, in_mask, in_len = _padded(inputs, np.int64)
+    src = src[in_mask]                     # every input, in node order
+    feats = np.empty((n, NODE_FEATURE_DIM), np.float64)
+    feats[:, :SHAPE_FEATS] = shape_sub
     c = SHAPE_FEATS
-    feats[:, c] = [float(len(n.shape)) for n in nodes]          # rank
-    feats[:, c + 1] = [float(n.dtype_bytes) for n in nodes]
+    feats[:, c] = rank
+    feats[:, c + 1] = dtype_bytes
     feats[:, c + 2] = 1.0                          # default row-major layout
-    feats[:, c + 3] = [1.0 if n.op is opset.PARAMETER else 0.0 for n in nodes]
-    feats[:, c + 4] = [1.0 if n.is_output else 0.0 for n in nodes]
-    feats[:, c + 5] = [float(len(n.inputs)) for n in nodes]
-    feats[:, c + 6] = g.fan_out()
+    feats[:, c + 3] = op_cols[:, 5]                # parameter
+    feats[:, c + 4] = outputs
+    feats[:, c + 5] = in_len                                    # fan-in
+    feats[:, c + 6] = np.bincount(src, minlength=n)             # fan-out
     c += 7
-    feats[:, c:c + 5] = _subvec_rows([n.reduced_dims for n in nodes], 2)
+    feats[:, c:c + 5] = _subvec_cols(red, red_mask, red_len, 2)
     c += 5
-    feats[:, c:c + 5] = _subvec_rows(
-        [n.filter_size if n.op is opset.CONV else () for n in nodes], 2)
+    feats[:, c:c + 5] = 0.0
+    if filters:
+        feats[conv, c:c + 5] = _subvec_rows(filters, 2)
     c += 5
-    feats[:, c] = [float(n.contract_dim) for n in nodes]
-    feats[:, c + 1] = np.log1p([n.flops() for n in nodes])
-    feats[:, c + 2] = np.log1p([float(n.bytes_out) for n in nodes])
-    feats[:, c + 3] = [1.0 if n.op.elementwise else 0.0 for n in nodes]
-    feats[:, c + 4] = [1.0 if n.op.transcendental else 0.0 for n in nodes]
-    return feats
+    feats[:, c] = contract
+    feats[:, c + 1] = np.log1p(flops)
+    feats[:, c + 2] = np.log1p(bytes_i.astype(np.float64))
+    feats[:, c + 3:c + 5] = op_cols[:, 2:4]    # elementwise, transcendental
+
+    # unique (src, dst) pairs in first-occurrence order, as unique_edges
+    dst = np.repeat(np.arange(n), in_len)
+    _, first = np.unique(src * n + dst, return_index=True)
+    first.sort()
+    edges = np.stack([src[first], dst[first]], axis=1).astype(np.int32)
+    return ops, feats, kernel_base, edges
+
+
+def node_features(g: KernelGraph) -> np.ndarray:
+    """Per-node scalar features: the fast path, from `_columnar`'s
+    columns. Matches `node_features_reference` bit for bit."""
+    cols = _columnar(g)
+    return node_features_reference(g) if cols is None else cols[1]
 
 
 def node_features_reference(g: KernelGraph) -> np.ndarray:
-    """The original per-node-loop encoder. Kept as the equivalence oracle
-    for tests and as the baseline for `benchmarks/bench_input_pipeline.py`
-    — not used on any hot path."""
+    """The original per-node-loop encoder: the oracle `node_features` is
+    tested against, and the baseline of
+    `benchmarks/bench_input_pipeline.py`. Not used on any hot path."""
     n_nodes = g.num_nodes
     fan_out = g.fan_out()
     feats = np.zeros((n_nodes, NODE_FEATURE_DIM), np.float64)
@@ -147,7 +251,7 @@ def node_features_reference(g: KernelGraph) -> np.ndarray:
             _subvec(n.reduced_dims, 2),
             _subvec(n.filter_size if n.op is opset.CONV else (), 2),
             np.array([float(n.contract_dim)]),
-            np.array([np.log1p(n.flops()), np.log1p(n.bytes_out)]),
+            np.array([np.log1p(n.flops()), np.log1p(float(n.bytes_out))]),
             np.array([1.0 if n.op.elementwise else 0.0,
                       1.0 if n.op.transcendental else 0.0]),
         ]
@@ -157,6 +261,10 @@ def node_features_reference(g: KernelGraph) -> np.ndarray:
 
 def kernel_features(g: KernelGraph, *, include_static_perf: bool = True,
                     include_tile: bool = True) -> np.ndarray:
+    """The kernel feature vector from `KernelGraph`'s static-analysis
+    methods: the oracle of the fast path (`graph_kernel_feats`,
+    `EncodedKernel.kernel_feats`), which keeps these sequential sums so
+    that both agree bit for bit."""
     tile = g.tile_size if include_tile else ()
     static = np.zeros((4,), np.float64)
     if include_static_perf:
@@ -272,12 +380,8 @@ class EncodedKernel:
         structural part and rewrite only `TILE_SLICE` (and zero
         `STATIC_PERF_SLICE` when the ablation asks for it). Bit-identical
         to `kernel_features(g.with_tile(tile), ...)`."""
-        kf = self.kernel_feats_base.copy()
-        if len(tile):
-            kf[TILE_SLICE] = _subvec(tile, TILE_SUBVEC)
-        if not include_static_perf:
-            kf[STATIC_PERF_SLICE] = 0.0
-        return kf
+        return _assemble_kernel_feats(self.kernel_feats_base, tile,
+                                      include_static_perf)
 
     def normalized_node_feats(self, normalizer: "FeatureNormalizer | None"
                               ) -> np.ndarray:
@@ -308,16 +412,48 @@ class EncodedKernel:
         return a
 
 
+def _assemble_kernel_feats(base: np.ndarray, tile: Sequence[int],
+                           include_static_perf: bool) -> np.ndarray:
+    kf = base.copy()
+    if len(tile):
+        kf[TILE_SLICE] = _subvec(tile, TILE_SUBVEC)
+    if not include_static_perf:
+        kf[STATIC_PERF_SLICE] = 0.0
+    return kf
+
+
 def _build_encoded(g: KernelGraph) -> EncodedKernel:
-    ops = opcode_ids(g)
-    nf = node_features(g)
-    kf = kernel_features(g, include_tile=False)
-    edges = np.asarray(g.unique_edges(), np.int32).reshape(-1, 2)
+    cols = _columnar(g)
+    return _build_encoded_reference(g) if cols is None else _encoded(g, *cols)
+
+
+def _build_encoded_reference(g: KernelGraph) -> EncodedKernel:
+    """`_build_encoded` from the per-node oracles: the path of graphs past
+    int64, and the baseline of `benchmarks/bench_input_pipeline.py`."""
+    return _encoded(g, opcode_ids(g), node_features_reference(g),
+                    kernel_features(g, include_tile=False),
+                    np.asarray(g.unique_edges(), np.int32).reshape(-1, 2))
+
+
+def _encoded(g: KernelGraph, ops, nf, kf, edges) -> EncodedKernel:
     for a in (ops, nf, kf, edges):
         a.setflags(write=False)
     return EncodedKernel(key=g.structural_digest(order_sensitive=True),
                          opcodes=ops, node_feats=nf, kernel_feats_base=kf,
                          edges=edges)
+
+
+def graph_kernel_feats(g: KernelGraph, *,
+                       include_static_perf: bool = True) -> np.ndarray:
+    """The kernel feature vector of `g`, its tile included, from the
+    columnar pass alone: no node-feature matrix, no `EncodeCache` entry —
+    what a whole program's readout reads. Equals `kernel_features(g, ...)`
+    and `encode_structural(g).kernel_feats(g.tile_size, ...)` bit for
+    bit."""
+    cols = _columnar(g, with_nodes=False)
+    base = (kernel_features(g, include_tile=False) if cols is None
+            else cols[2])
+    return _assemble_kernel_feats(base, g.tile_size, include_static_perf)
 
 
 @dataclass(frozen=True)
@@ -708,6 +844,6 @@ def encode_batch(graphs: Sequence[KernelGraph], n_max: int,
 def fit_normalizer(graphs: Sequence[KernelGraph],
                    *, include_static_perf: bool = True) -> FeatureNormalizer:
     nfs = [node_features(g) for g in graphs]
-    kfs = [kernel_features(g, include_static_perf=include_static_perf)
+    kfs = [graph_kernel_feats(g, include_static_perf=include_static_perf)
            for g in graphs]
     return FeatureNormalizer.fit(nfs, kfs)

@@ -234,35 +234,27 @@ def encode_segmented(graphs: Sequence[KernelGraph], node_budget: int,
     n_off = 0         # running node offset inside the inner flat buffer
     g_off = 0         # running node offset in the outer whole-graph buffer
     for gi, (g, sg) in enumerate(zip(graphs, segs)):
+        # the program's own kernel feats, for its outer slot and for every
+        # segment slot, so the kernel_feat_mode='node' broadcast sees the
+        # *program's* features, not the block's (identity path: identical
+        # values); no node features of the whole program are built
+        whole_kf = F.graph_kernel_feats(
+            g, include_static_perf=include_static_perf)
+        if normalizer is not None:
+            whole_kf = normalizer.transform_kernel(whole_kf)
         for s in sg.segments:
-            base = n_off                      # segment's inner node offset
-            for loc, glob in zip(s.owned_local, s.owned_global):
-                scatter[base + loc] = g_off + glob
+            scatter[n_off + np.asarray(s.owned_local, np.int64)] = (
+                g_off + np.asarray(s.owned_global, np.int64))
             n_off += s.graph.num_nodes
-            # whole-graph kernel feats for every segment slot, so the
-            # kernel_feat_mode='node' broadcast sees the *program's*
-            # features, not the block's (identity path: identical values)
-            inner.kernel_feats[slot] = _whole_kernel_feats(
-                g, normalizer, include_static_perf=include_static_perf)
+            inner.kernel_feats[slot] = whole_kf
             slot += 1
         n = g.num_nodes
         node_mask[g_off:g_off + n] = 1.0
         graph_ids[g_off:g_off + n] = gi
-        kf[gi] = _whole_kernel_feats(
-            g, normalizer, include_static_perf=include_static_perf)
+        kf[gi] = whole_kf
         graph_mask[gi] = 1.0
         gather_idx[gi, :n] = np.arange(g_off, g_off + n, dtype=np.int32)
         gather_mask[gi, :n] = 1.0
         g_off += n
     return F.SegmentedGraphBatch(inner, scatter, node_mask, graph_ids,
                                  kf, graph_mask, gather_idx, gather_mask)
-
-
-def _whole_kernel_feats(g: KernelGraph,
-                        normalizer: FeatureNormalizer | None,
-                        *, include_static_perf: bool) -> np.ndarray:
-    kf = F.encode_structural(g).kernel_feats(
-        g.tile_size, include_static_perf=include_static_perf)
-    if normalizer is not None:
-        kf = normalizer.transform_kernel(kf)
-    return kf

@@ -15,11 +15,12 @@ import numpy as np
 import pytest
 
 from repro.core import features as F
+from repro.core import opset
 from repro.core.graph import KernelGraph
 from repro.core.simulator import TPUSimulator
 from repro.data.prefetch import Prefetcher
 from repro.data.sampler import BalancedSampler, TileBatchSampler
-from repro.data.synthetic import generate_corpus, random_kernel
+from repro.data.synthetic import FAMILIES, generate_corpus, random_kernel
 from repro.data.tile_dataset import build_tile_dataset, fit_tile_normalizer
 
 
@@ -68,6 +69,98 @@ def test_node_features_matches_reference_bitwise():
     for g in graphs:
         assert np.array_equal(F.node_features(g),
                               F.node_features_reference(g))
+
+
+def _family_block(fam):
+    return FAMILIES[fam](np.random.default_rng(len(fam)), f"block_{fam}")
+
+
+def _handmade(*nodes, outputs=True):
+    """A graph of `Node(op, shape, inputs, **kw)` specs; the last node is
+    the output unless `outputs` is False."""
+    from repro.core.graph import Node
+    out = [Node(op, shape, inputs=inputs, **kw)
+           for op, shape, inputs, kw in nodes]
+    if outputs:
+        out[-1] = dataclasses.replace(out[-1], is_output=True)
+    return KernelGraph(out, name="handmade")
+
+
+def _whole_model():
+    from repro.data.synthetic import whole_model_graph
+    return whole_model_graph(1500, seed=3)
+
+
+def _smoke_dsv3():
+    from repro.core.hlo_import import import_arch_program
+    return import_arch_program("deepseek-v3-671b")
+
+
+# (_BIG, _BIG + 12, 3): a volume past 2**53 whose f64 product of dims
+# rounds off the exact one by enough to move its log1p
+_BIG = 2 ** 27 + 1
+ENCODE_CASES = {
+    **{f"family-{fam}": (lambda fam=fam: _family_block(fam))
+       for fam in FAMILIES},
+    "whole-model": _whole_model,
+    "dsv3-smoke": _smoke_dsv3,
+    "conv-filters": lambda: _handmade(
+        (opset.PARAMETER, (2, 16, 16, 8), (), {}),
+        (opset.PARAMETER, (3, 5, 8, 4), (), {}),
+        (opset.CONV, (2, 16, 16, 4), (0, 1),
+         {"contract_dim": 8, "filter_size": (3, 5)}),
+        (opset.CONV, (2, 16, 16, 4), (2, 1),
+         {"contract_dim": 0, "filter_size": (0, 7)})),
+    "empty-shapes-and-reduced": lambda: _handmade(
+        (opset.PARAMETER, (), (), {}),
+        (opset.CONSTANT, (4, 0), (), {}),
+        (opset.REDUCE_SUM, (), (0,), {"reduced_dims": ()}),
+        (opset.REDUCE_MAX, (1,), (1,), {"reduced_dims": (4, 0)}),
+        (opset.ADD, (), (2, 3), {})),
+    "one-producer-twice": lambda: _handmade(
+        (opset.PARAMETER, (8, 8), (), {}),
+        (opset.MUL, (8, 8), (0, 0), {}),
+        (opset.SELECT, (8, 8), (1, 0, 1), {})),
+    "no-output-flag": lambda: _handmade(
+        (opset.PARAMETER, (2, 3), (), {}),
+        (opset.EXP, (2, 3), (0,), {}),
+        (opset.BROADCAST, (8, 8), (1,), {}), outputs=False),
+    "volume-past-2**53": lambda: _handmade(
+        (opset.PARAMETER, (_BIG, _BIG + 12, 3), (), {}),
+        (opset.EXP, (_BIG, _BIG + 12, 3), (0,), {}),
+        (opset.DOT, (_BIG, 3, 5), (1, 0), {"contract_dim": _BIG}),
+        (opset.REDUCE_SUM, (3, 7), (2,), {"reduced_dims": (_BIG,)}),
+        (opset.NEG, (3, 7), (3,), {})),
+    "volume-past-int64": lambda: _handmade(
+        (opset.PARAMETER, (2 ** 40, 2 ** 30 + 1), (), {}),
+        (opset.LOG, (2 ** 40, 2 ** 30 + 1), (0,), {})),
+    "one-node": lambda: _handmade((opset.PARAMETER, (3, 4), (), {})),
+}
+
+
+@pytest.mark.parametrize("case", list(ENCODE_CASES))
+def test_columnar_encode_matches_oracles_bitwise(case):
+    """The columnar `_build_encoded` equals the per-node oracles array for
+    array, and `graph_kernel_feats` equals `kernel_features`."""
+    g = ENCODE_CASES[case]()
+    enc = F._build_encoded(g)
+    tiled = g.with_tile((4, 16))
+    oracle = {
+        "opcodes": F.opcode_ids(g),
+        "node_feats": F.node_features_reference(g),
+        "kernel_feats_base": F.kernel_features(g, include_tile=False),
+        "edges": np.asarray(g.unique_edges(), np.int32).reshape(-1, 2),
+    }
+    for name, want in oracle.items():
+        got = getattr(enc, name)
+        assert got.dtype == want.dtype and got.shape == want.shape, name
+        assert np.array_equal(got, want), name
+    assert enc.key == g.structural_digest(order_sensitive=True)
+    assert np.array_equal(F.node_features(g), oracle["node_feats"])
+    for static in (True, False):
+        assert np.array_equal(
+            F.graph_kernel_feats(tiled, include_static_perf=static),
+            F.kernel_features(tiled, include_static_perf=static))
 
 
 def test_subvec_rows_matches_subvec():

@@ -164,3 +164,86 @@ def test_segmented_whole_model_forward_finite():
     y = np.asarray(cost_model_apply(params, cfg, sb))
     assert y.shape == (2,)
     assert np.all(np.isfinite(y))
+
+
+def _segmented_by_whole_encode(graphs, budget, normalizer,
+                               include_static_perf):
+    """`encode_segmented` as it read each program's kernel feats off the
+    program's whole structural encode, once per segment slot and again for
+    its outer slot; the oracle of the program-only kernel feats."""
+    segs = [segment_graph(g, budget) for g in graphs]
+    inner = batching.encode_packed([s.graph for sg in segs
+                                    for s in sg.segments], normalizer,
+                                   include_static_perf=include_static_perf)
+    M = batching.round_up_pow2(sum(g.num_nodes for g in graphs), 32)
+    R = batching.round_up_pow2(max(g.num_nodes for g in graphs), 8)
+    G = len(graphs)
+    scatter = np.full((inner.num_nodes,), M, np.int32)
+    node_mask = np.zeros((M,), np.float32)
+    graph_ids = np.zeros((M,), np.int32)
+    kf = np.zeros((G, F.KERNEL_FEATURE_DIM), np.float32)
+    gather_idx = np.full((G, R), M, np.int32)
+    gather_mask = np.zeros((G, R), np.float32)
+
+    def whole(g):
+        out = F._build_encoded_reference(g).kernel_feats(
+            g.tile_size, include_static_perf=include_static_perf)
+        return out if normalizer is None else \
+            normalizer.transform_kernel(out)
+
+    slot = n_off = g_off = 0
+    for gi, (g, sg) in enumerate(zip(graphs, segs)):
+        for s in sg.segments:
+            for loc, glob in zip(s.owned_local, s.owned_global):
+                scatter[n_off + loc] = g_off + glob
+            n_off += s.graph.num_nodes
+            inner.kernel_feats[slot] = whole(g)
+            slot += 1
+        n = g.num_nodes
+        node_mask[g_off:g_off + n] = 1.0
+        graph_ids[g_off:g_off + n] = gi
+        kf[gi] = whole(g)
+        gather_idx[gi, :n] = np.arange(g_off, g_off + n)
+        gather_mask[gi, :n] = 1.0
+        g_off += n
+    return F.SegmentedGraphBatch(inner, scatter, node_mask, graph_ids, kf,
+                                 np.ones((G,), np.float32), gather_idx,
+                                 gather_mask)
+
+
+@pytest.mark.parametrize("static", [True, False])
+def test_segmented_encode_reads_only_the_programs_kernel_feats(
+        static, monkeypatch):
+    """Past the node budget, `encode_segmented` gives the batch the whole
+    program's structural encode gave, byte for byte, while it encodes only
+    the segments' node features: the program itself is neither encoded
+    with its nodes nor cached."""
+    graphs = [whole_model_graph(700, seed=4).with_tile((8, 16)),
+              random_kernel(30, seed=2)]
+    norm = _norm(graphs)
+    want = _segmented_by_whole_encode(graphs, 160, norm, static)
+    calls = []
+    columnar = F._columnar
+
+    def spy(g, **kw):
+        calls.append((g.num_nodes, kw.get("with_nodes", True)))
+        return columnar(g, **kw)
+
+    monkeypatch.setattr(F, "_columnar", spy)
+    cache = F.EncodeCache(4096)
+    old = F.set_encode_cache(cache)
+    try:
+        got = batching.encode_segmented(graphs, 160, norm,
+                                        include_static_perf=static)
+        segs = [segment_graph(g, 160) for g in graphs]
+    finally:
+        F.set_encode_cache(old)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.tobytes() == b.tobytes()
+    n_segments = sum(sg.num_segments for sg in segs)
+    assert segs[0].num_segments > 1
+    assert cache.stats().misses == n_segments
+    assert (graphs[0].num_nodes, True) not in calls
+    assert calls.count((graphs[0].num_nodes, False)) == 1
