@@ -71,11 +71,11 @@ def main():
     ap.add_argument("--store", default="",
                     help="corpus store root: built on the first run, "
                          "streamed from disk on every later run")
-    ap.add_argument("--dp", type=int, default=0,
-                    help="data-parallel mesh size (0 = single-device path; "
-                         ">=1 runs the mesh train step on dp local devices "
-                         "— on CPU, export XLA_FLAGS=--xla_force_host_"
-                         "platform_device_count=<dp> first)")
+    ap.add_argument("--dp", type=int, default=1,
+                    help="data-parallel mesh size: the mesh train step runs "
+                         "on dp local devices (on CPU, export XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=<dp> "
+                         "first)")
     ap.add_argument("--num-hosts", type=int, default=1,
                     help="total training hosts (the sampler draws from "
                          "this host's disjoint record shard)")

@@ -281,8 +281,8 @@ class GlobalBatchSampler:
     `batch(step)` stays a pure function of (seed, step, shard ids), so the
     wrapper composes with `repro.data.prefetch.Prefetcher` unchanged and a
     1-shard global stream is the base sampler's stream with a length-1
-    leading axis — nothing else differs, which is what the dp=1
-    bit-parity gate in benchmarks/bench_scaling.py checks end to end.
+    leading axis. Segmented batches have no shape shared across shards, so
+    they stack only at one shard.
     """
 
     def __init__(self, samplers):
@@ -294,11 +294,11 @@ class GlobalBatchSampler:
         adjs = {s.adjacency for s in samplers}
         if len(adjs) > 1:
             raise ValueError(f"mixed adjacencies {adjs}")
-        if samplers[0].adjacency == "segmented":
-            raise ValueError("segmented batches are not mesh-shardable "
-                             "(no uniform leading axis) — use adjacency="
-                             "'dense' or 'sparse' for data-parallel "
-                             "training")
+        if samplers[0].adjacency == "segmented" and len(samplers) > 1:
+            raise ValueError("segmented batches have no shape shared across "
+                             "shards — use adjacency='dense' or 'sparse' "
+                             "for data-parallel training; segmented "
+                             "training runs at dp=1")
         self.samplers = list(samplers)
         self.adjacency = samplers[0].adjacency
 
@@ -336,22 +336,16 @@ class GlobalBatchSampler:
 
 
 def _stack_batches(parts):
-    """Stack equally-shaped sub-batches leaf-wise into a [dp, ...] batch.
+    """Stack equally-shaped sub-batches leaf-wise into a [dp, ...] batch,
+    recursing into nested batch dataclasses (`SegmentedGraphBatch.inner`).
     Works on the batch dataclasses directly (numpy, no jax import) so the
     Prefetcher worker thread can run it too."""
     b0 = parts[0]
-    kw = {}
-    for f in dataclasses.fields(b0):
-        vals = [getattr(p, f.name) for p in parts]
-        if dataclasses.is_dataclass(vals[0]):        # the graphs pytree
-            g0 = vals[0]
-            kw[f.name] = type(g0)(**{
-                gf.name: np.stack([np.asarray(getattr(v, gf.name))
-                                   for v in vals])
-                for gf in dataclasses.fields(g0)})
-        else:
-            kw[f.name] = np.stack([np.asarray(v) for v in vals])
-    return type(b0)(**kw)
+    if not dataclasses.is_dataclass(b0):
+        return np.stack([np.asarray(p) for p in parts])
+    return type(b0)(**{
+        f.name: _stack_batches([getattr(p, f.name) for p in parts])
+        for f in dataclasses.fields(b0)})
 
 
 class ShardPlanner:

@@ -55,9 +55,8 @@ def train_cost_model(args) -> None:
     if not 0 <= args.host_id < args.num_hosts:
         raise SystemExit(f"--host-id must be in [0, {args.num_hosts}), "
                          f"got {args.host_id}")
-    if args.dp < 0 or args.mp < 1:
-        raise SystemExit(f"--dp must be >= 0 and --mp >= 1, "
-                         f"got dp={args.dp} mp={args.mp}")
+    if args.dp < 1:
+        raise SystemExit(f"--dp must be >= 1, got {args.dp}")
 
     if args.deltas and not args.from_store:
         raise SystemExit("--deltas only applies to a stored corpus; "
@@ -126,7 +125,7 @@ def train_cost_model(args) -> None:
                        ckpt_dir=args.ckpt_dir,
                        metrics_path=args.metrics_path,
                        compress_grads=args.compress_grads,
-                       dp=args.dp, mp=args.mp,
+                       dp=args.dp,
                        optim=AdamWConfig(lr=args.lr,
                                          warmup_steps=args.warmup_steps))
     trainer = CostModelTrainer(mc, tc, sampler)
@@ -213,12 +212,9 @@ def main() -> None:
     cm.add_argument("--metrics-path", default="")
     cm.add_argument("--compress-grads", action="store_true")
     cm.add_argument("--no-resume", action="store_true")
-    cm.add_argument("--dp", type=int, default=0,
-                    help="data-parallel mesh size (0 = legacy single-device "
-                         "path; >=1 runs the mesh train step, DESIGN.md "
-                         "§13)")
-    cm.add_argument("--mp", type=int, default=1,
-                    help="model mesh axis size (params replicated)")
+    cm.add_argument("--dp", type=int, default=1,
+                    help="data-parallel mesh size: the mesh train step "
+                         "runs on dp local devices (DESIGN.md §13)")
     cm.add_argument("--num-hosts", type=int, default=1,
                     help="total training hosts; this host's sampler draws "
                          "from its disjoint record shard")

@@ -2,8 +2,8 @@
 
 `repro.launch.mesh` builds the *production LM* meshes (dp × fsdp × tp over
 512 devices, checked by the dryrun probes). The cost-model trainer needs
-something much smaller: a dp (× optional mp) mesh over however many local
-devices the process actually has — 2 fake CPU devices under
+something much smaller: a dp mesh over however many local devices the
+process actually has — 2 fake CPU devices under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=2`` in CI, real
 accelerators in production. This module is that factory, kept in
 `repro.sharding` so the trainer never imports launch code.
@@ -19,26 +19,21 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
-def make_train_mesh(dp: int, mp: int = 1) -> Mesh:
-    """A ``(dp, mp)`` mesh with axes ``("data", "model")`` over the first
-    ``dp * mp`` local devices.
-
-    The model axis exists even at ``mp == 1`` so a trainer compiled against
-    the two-axis layout needs no special case; cost-model params are
-    replicated over both axes today, and a future tensor-parallel GNN only
-    has to partition over the already-present ``"model"`` axis.
+def make_train_mesh(dp: int) -> Mesh:
+    """A ``(dp, 1)`` mesh with axes ``("data", "model")`` over the first
+    `dp` local devices. Cost-model params are replicated; the size-1 model
+    axis keeps the layout the chip benchmark builds for its train cells.
 
     Raises ValueError when the host doesn't have enough devices — the
     actionable fix on CPU hosts is in the message.
     """
-    if dp < 1 or mp < 1:
-        raise ValueError(f"mesh axes must be >= 1, got dp={dp} mp={mp}")
-    need = dp * mp
+    if dp < 1:
+        raise ValueError(f"mesh dp must be >= 1, got dp={dp}")
     devices = jax.devices()
-    if len(devices) < need:
+    if len(devices) < dp:
         raise ValueError(
-            f"mesh dp={dp} x mp={mp} needs {need} devices but only "
-            f"{len(devices)} are visible; on CPU hosts set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
-    grid = np.asarray(devices[:need]).reshape(dp, mp)
+            f"mesh dp={dp} needs {dp} devices but only {len(devices)} are "
+            f"visible; on CPU hosts set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={dp}")
+    grid = np.asarray(devices[:dp]).reshape(dp, 1)
     return Mesh(grid, (DATA_AXIS, MODEL_AXIS))

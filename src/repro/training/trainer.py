@@ -1,5 +1,5 @@
-"""Cost-model trainer: pjit/shard_map distribution, fault tolerance,
-checkpoint/resume, optional int8-compressed data parallelism.
+"""Cost-model trainer: one shard_map train step over a data-parallel mesh,
+fault tolerance, checkpoint/resume, optional int8-compressed gradients.
 
 The trainer is deliberately framework-grade rather than script-grade:
   * deterministic batch streams (seed, step, host) — restart-reproducible,
@@ -10,12 +10,12 @@ The trainer is deliberately framework-grade rather than script-grade:
     (the model is ~1-10M params — DP is the right parallelism; the LM zoo
     under repro.models exercises TP/FSDP/EP/SP instead).
 
-Batches are whatever the sampler yields: dense `features.GraphBatch` or
-packed `features.SparseGraphBatch` (adjacency='sparse'; DESIGN.md §4). The
+Batches are whatever the sampler yields: dense `features.GraphBatch`,
+packed `features.SparseGraphBatch` (adjacency='sparse'; DESIGN.md §4) or
+`features.SegmentedGraphBatch` (adjacency='segmented'; DESIGN.md §12). The
 jit step caches one executable per batch shape, so sparse batches must come
 from the pow2-bucketed batcher in `repro.data.batching` to bound
-recompilation. Sparse batches have no uniform leading batch dim, so the
-int8 compressed-DP path (which shards on it) is dense-only.
+recompilation.
 
 With `TrainerConfig.prefetch > 0` the sampler is wrapped in a
 `repro.data.prefetch.Prefetcher`: a background thread encodes that many
@@ -28,25 +28,24 @@ batches draw them, with a byte-identical batch stream to in-memory records
 — `python -m repro.launch.train cost-model --from-store` is this path
 (DESIGN.md §11, docs/DATA.md).
 
-With `TrainerConfig.dp >= 1` the trainer runs the *mesh train step*
-(DESIGN.md §13): a ``(dp, mp)`` mesh from `repro.sharding.make_train_mesh`,
+The trainer has one train step, the *mesh train step* (DESIGN.md §13): a
+``(dp, 1)`` ``(data, model)`` mesh from `repro.sharding.make_train_mesh`,
 the sampler wrapped in a `GlobalBatchSampler` whose batches carry a leading
 [dp] device axis (each device trains on its own disjoint record shard),
-per-device forward/backward under `shard_map` with psum'd loss and grads
-— int8-compressed when `compress_grads` (which composes with sparse
-batches here: the *global* batch has the leading axis the legacy path
-lacked). ``dp=1`` is bit-identical to the legacy jit path — same batch
-stream, same rng fold, pmean over a size-1 axis is exact.
+per-device forward/backward under `shard_map` with pmean'd loss and grads
+— int8-compressed when `compress_grads`. At ``dp=1`` the global stream is
+the base sampler's stream with a length-1 leading axis. Segmented batches
+have no shared shape across shards, so segmented training runs at dp=1
+only.
 
-Every step function takes ``key(seed + 1)``, made once, and the step
-number as an ``np.int32`` argument, and folds the step's dropout keys
-from them inside the jit (`step_keys`): the ladder
-``fold_in(key(seed + 1), step*dp + d)`` for device d, or
-``fold_in(key(seed + 1), step)`` at dp=0. Threefry gives the same bits
-traced as eager, so no eager JAX op runs between the batch and the
-launch, and the keys are those a host fold would give. Checkpoints are
-written by process 0 only and restore onto any dp layout (error-feedback
-buffers, the one per-device-layout state, restart at zero across layouts).
+The step takes ``key(seed + 1)``, made once, and the step number as an
+``np.int32`` argument, and folds the step's dropout keys from them inside
+the jit (`step_keys`): the ladder ``fold_in(key(seed + 1), step*dp + d)``
+for device d. Threefry gives the same bits traced as eager, so no eager JAX
+op runs between the batch and the launch, and the keys are those a host
+fold would give. Checkpoints are written by process 0 only and restore
+onto any dp layout (error-feedback buffers, the one per-device-layout
+state, restart at zero across layouts).
 """
 from __future__ import annotations
 
@@ -65,6 +64,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.losses import log_mse_loss, mse_loss, pairwise_rank_loss
 from repro.core.model import CostModelConfig, cost_model_apply, cost_model_init
+from repro.data.sampler import GlobalBatchSampler
+from repro.sharding.context import (activation_sharding,
+                                    constrain_batch_tree, shard_map_nocheck)
+from repro.sharding.mesh import DATA_AXIS, MODEL_AXIS, make_train_mesh
 from repro.spans import span
 from repro.training import checkpoint as ckpt_lib
 from repro.training.compression import compressed_allreduce, zeros_like_error
@@ -83,13 +86,10 @@ class TrainerConfig:
     ckpt_dir: str = ""
     metrics_path: str = ""
     compress_grads: bool = False          # int8 + error feedback over DP axis
-    data_axis: str = "data"
-    # mesh train step (DESIGN.md §13): dp=0 keeps the legacy single-device
-    # jit path bit-for-bit; dp>=1 builds a (dp, mp) mesh, wraps the sampler
-    # in a GlobalBatchSampler and shards the leading batch axis over
-    # `data_axis`. dp=1 is bit-identical to dp=0 (bench_scaling gates it).
-    dp: int = 0
-    mp: int = 1                           # model axis size (params replicated)
+    # mesh train step (DESIGN.md §13): a (dp, 1) (data, model) mesh; the
+    # sampler is wrapped in a GlobalBatchSampler and each device trains on
+    # its own record shard. Segmented batches train at dp=1 only.
+    dp: int = 1
     # async input pipeline (DESIGN.md §9): number of batches a background
     # thread encodes ahead of the jitted step (0 = synchronous encode). The
     # delivered batch stream is byte-identical either way; `.run` owns the
@@ -99,21 +99,12 @@ class TrainerConfig:
     optim: AdamWConfig = field(default_factory=AdamWConfig)
 
 
-def step_keys(base, step, dp: int = 0):
+def step_keys(base, step, dp: int):
     """The dropout keys of train step `step` from ``base = key(seed + 1)``,
-    traceable in both: the ladder ``fold_in(base, step*dp + d)``, one key
-    for each device d of `dp` (a ``[dp]`` key array), or one key
-    ``fold_in(base, step)`` at dp=0. dp=1's key is dp=0's, so the mesh
-    step at dp=1 draws the legacy path's dropout masks."""
-    if dp == 0:
-        return jax.random.fold_in(base, step)
+    traceable: the ladder ``fold_in(base, step*dp + d)``, one key for each
+    device d of `dp` (a ``[dp]`` key array)."""
     return jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
         base, step * dp + jnp.arange(dp))
-
-
-def make_mesh_1d(axis: str = "data") -> Mesh:
-    devs = np.array(jax.devices())
-    return Mesh(devs.reshape(-1), (axis,))
 
 
 class CostModelTrainer:
@@ -124,11 +115,9 @@ class CostModelTrainer:
         self.step = 0
         self._stop = False
         self._metrics_f = None
-        self._use_mesh = cfg.dp >= 1
 
-        if cfg.dp < 0 or cfg.mp < 1:
-            raise ValueError(f"dp must be >= 0 and mp >= 1, "
-                             f"got dp={cfg.dp} mp={cfg.mp}")
+        if cfg.dp < 1:
+            raise ValueError(f"TrainerConfig.dp must be >= 1, got {cfg.dp}")
 
         if model_cfg.precision != "f32":
             raise ValueError(
@@ -138,19 +127,12 @@ class CostModelTrainer:
 
         # reject dense-only config combos here rather than as a
         # NotImplementedError buried in the first step's jit trace
-        if self._use_mesh and model_cfg.adjacency == "segmented":
+        if cfg.dp > 1 and model_cfg.adjacency == "segmented":
             raise ValueError(
-                "segmented batches have no uniform leading axis to shard "
-                "over the mesh — use adjacency='dense' or 'sparse' with "
-                "TrainerConfig.dp")
+                "segmented batches have no shape shared across shards, so "
+                "segmented training runs at dp=1 — use adjacency='dense' "
+                "or 'sparse' for TrainerConfig.dp > 1")
         if model_cfg.adjacency in ("sparse", "segmented"):
-            if cfg.compress_grads and not self._use_mesh:
-                raise ValueError(
-                    "compress_grads=True needs a leading batch dim to shard "
-                    "and packed sparse batches have none; the mesh train "
-                    "step stacks per-device sub-batches with one — set "
-                    "TrainerConfig.dp >= 1 (compress_grads composes with "
-                    "adjacency='sparse' there) or use adjacency='dense'")
             if model_cfg.use_pallas_aggregate:
                 raise ValueError(
                     "use_pallas_aggregate on the sparse layouts routes "
@@ -162,36 +144,24 @@ class CostModelTrainer:
                     "undirected GAT is dense-only (DESIGN.md §4) — use "
                     "adjacency='dense'")
 
-        if self._use_mesh:
-            from repro.data.sampler import GlobalBatchSampler
-            from repro.sharding.mesh import DATA_AXIS, make_train_mesh
-            if cfg.data_axis != DATA_AXIS:
+        self.mesh = mesh or make_train_mesh(cfg.dp)
+        if isinstance(sampler, GlobalBatchSampler):
+            if sampler.num_shards != cfg.dp:
                 raise ValueError(
-                    f"the mesh train step uses axis {DATA_AXIS!r}; got "
-                    f"data_axis={cfg.data_axis!r}")
-            self.mesh = mesh or make_train_mesh(cfg.dp, cfg.mp)
-            if isinstance(sampler, GlobalBatchSampler):
-                if sampler.num_shards != cfg.dp:
-                    raise ValueError(
-                        f"GlobalBatchSampler has {sampler.num_shards} "
-                        f"shards but dp={cfg.dp}")
-                self.sampler = sampler
-            else:
-                self.sampler = GlobalBatchSampler.for_mesh(sampler, cfg.dp)
-        else:
-            self.mesh = mesh or make_mesh_1d(cfg.data_axis)
+                    f"GlobalBatchSampler has {sampler.num_shards} "
+                    f"shards but dp={cfg.dp}")
             self.sampler = sampler
+        else:
+            self.sampler = GlobalBatchSampler.for_mesh(sampler, cfg.dp)
 
         key = jax.random.key(cfg.seed)
         self.params = cost_model_init(key, model_cfg)
         self.opt_state = adamw_init(self.params)
         if cfg.compress_grads:
-            ef = zeros_like_error(self.params)
-            if self._use_mesh:
-                # per-DEVICE residuals: leading [dp] axis, sharded P(data)
-                ef = jax.tree_util.tree_map(
-                    lambda x: jnp.zeros((cfg.dp,) + x.shape, x.dtype), ef)
-            self.opt_state["ef"] = ef
+            # per-DEVICE residuals: leading [dp] axis, sharded P(data)
+            self.opt_state["ef"] = jax.tree_util.tree_map(
+                lambda x: jnp.zeros((cfg.dp,) + x.shape, x.dtype),
+                zeros_like_error(self.params))
 
         # an argument of every step, not a constant of its trace, so one
         # executable (and one persistent-cache entry) serves every seed
@@ -215,90 +185,21 @@ class CostModelTrainer:
         raise ValueError(f"unknown task {self.cfg.task!r}")
 
     def _build_train_step(self):
-        if self._use_mesh:
-            return self._build_mesh_step()
-        cfg = self.cfg
-        mesh = self.mesh
-        data_spec = P(cfg.data_axis)
-        repl = NamedSharding(mesh, P())
-
-        def batch_shardings(batch_tree):
-            def spec_for(x):
-                if x.ndim >= 1:
-                    return NamedSharding(mesh, data_spec)
-                return repl
-            return jax.tree_util.tree_map(spec_for, batch_tree)
-
-        if not cfg.compress_grads:
-            @partial(jax.jit, donate_argnums=(0,))
-            def train_step(params, opt_state, batch, targets, group_ids,
-                           valid, base, step):
-                loss, grads = jax.value_and_grad(self._loss_fn)(
-                    params, batch, targets, group_ids, valid,
-                    step_keys(base, step))
-                new_params, new_opt, stats = adamw_update(
-                    params, grads, opt_state, cfg.optim)
-                stats["loss"] = loss
-                return new_params, new_opt, stats
-            self._batch_shardings = batch_shardings
-            return train_step
-
-        # compressed-DP path: per-device grads + int8 all-reduce
-        axis = cfg.data_axis
-
-        def shmap_step(params, opt_state, batch, targets, group_ids, valid,
-                       base, step):
-            ef = opt_state["ef"]
-            rng = step_keys(base, step)
-
-            def local(params, batch, targets, group_ids, valid, ef):
-                loss, grads = jax.value_and_grad(self._loss_fn)(
-                    params, batch, targets, group_ids, valid, rng)
-                red, new_ef = compressed_allreduce(grads, ef, axis)
-                loss = jax.lax.pmean(loss, axis)
-                return loss, red, new_ef
-
-            from repro.sharding.context import shard_map_nocheck
-            spec_params = jax.tree_util.tree_map(lambda _: P(), params)
-            spec_batch = jax.tree_util.tree_map(
-                lambda x: P(axis) if x.ndim >= 1 else P(), batch)
-            loss, grads, new_ef = shard_map_nocheck(
-                local, mesh,
-                in_specs=(spec_params, spec_batch, P(axis), P(axis), P(axis),
-                          jax.tree_util.tree_map(lambda _: P(), ef)),
-                out_specs=(P(), jax.tree_util.tree_map(lambda _: P(), params),
-                           jax.tree_util.tree_map(lambda _: P(), ef)),
-            )(params, batch, targets, group_ids, valid, ef)
-            opt_no_ef = {k: v for k, v in opt_state.items() if k != "ef"}
-            new_params, new_opt, stats = adamw_update(
-                params, grads, opt_no_ef, cfg.optim)
-            new_opt["ef"] = new_ef
-            stats["loss"] = loss
-            return new_params, new_opt, stats
-
-        self._batch_shardings = batch_shardings
-        return jax.jit(shmap_step, donate_argnums=(0,))
-
-    def _build_mesh_step(self):
-        """The dp (x mp) mesh train step (DESIGN.md §13).
+        """The dp mesh train step (DESIGN.md §13).
 
         Inputs carry a leading [dp] device axis (GlobalBatchSampler); the
-        step shards it over `data_axis`, runs the per-device
-        forward/backward under shard_map, and psums loss + grads (int8
+        step shards it over the data axis, runs the per-device
+        forward/backward under shard_map, and pmeans loss + grads (int8
         `compressed_allreduce` when `compress_grads` — its error-feedback
         residuals live in `opt_state['ef']` with the same leading [dp]
         axis). The optimizer update runs once on the replicated mean
         gradient outside the shard_map, so params never diverge across
-        devices. dp=1 is bit-identical to the legacy jit path: identical
-        batch, identical rng, and psum/pmean over a size-1 axis is exact.
+        devices.
         """
         cfg = self.cfg
         mesh = self.mesh
-        axis = cfg.data_axis
+        axis = DATA_AXIS
         compress = cfg.compress_grads
-
-        from repro.sharding.context import (constrain_batch_tree,
-                                            shard_map_nocheck)
 
         def repl(tree):
             return jax.tree_util.tree_map(lambda _: P(), tree)
@@ -344,7 +245,6 @@ class CostModelTrainer:
             stats["loss"] = loss
             return new_params, new_opt, stats
 
-        self._batch_shardings = None
         return mesh_step
 
     # ------------------------------------------------------------------
@@ -380,12 +280,10 @@ class CostModelTrainer:
         """NamedSharding tree for `like`: everything replicated over the
         mesh except the per-device error-feedback residuals, which shard
         their leading [dp] axis over the data axis."""
-        if not self._use_mesh:
-            return None
         repl = NamedSharding(self.mesh, P())
         sh = jax.tree_util.tree_map(lambda _: repl, like)
         if "ef" in like.get("opt", {}):
-            dps = NamedSharding(self.mesh, P(self.cfg.data_axis))
+            dps = NamedSharding(self.mesh, P(DATA_AXIS))
             sh["opt"]["ef"] = jax.tree_util.tree_map(
                 lambda _: dps, like["opt"]["ef"])
         return sh
@@ -485,15 +383,11 @@ class CostModelTrainer:
                                      start_step=self.step,
                                      device_put=cfg.prefetch_device_put)
             try:
-                if self._use_mesh:
-                    from repro.sharding.context import activation_sharding
-                    mapping = {"dp": cfg.data_axis,
-                               "axis_sizes": {cfg.data_axis: cfg.dp,
-                                              "model": cfg.mp}}
-                    with self.mesh, activation_sharding(mapping):
-                        return self._run_loop(sampler, total, eval_fn,
-                                              eval_every)
-                return self._run_loop(sampler, total, eval_fn, eval_every)
+                mapping = {"dp": DATA_AXIS,
+                           "axis_sizes": {DATA_AXIS: cfg.dp, MODEL_AXIS: 1}}
+                with self.mesh, activation_sharding(mapping):
+                    return self._run_loop(sampler, total, eval_fn,
+                                          eval_every)
             finally:
                 if sampler is not self.sampler:
                     sampler.close()

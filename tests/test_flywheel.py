@@ -253,6 +253,12 @@ def test_cli_deltas_requires_from_store(monkeypatch):
         _run_cli(monkeypatch, "cost-model", "--deltas")
 
 
+@pytest.mark.parametrize("dp", ["0", "-1"])
+def test_cli_rejects_dp_below_one(monkeypatch, dp):
+    with pytest.raises(SystemExit, match="--dp must be >= 1"):
+        _run_cli(monkeypatch, "cost-model", "--dp", dp)
+
+
 def test_cli_warm_start_needs_existing_checkpoint(monkeypatch, tmp_path):
     with pytest.raises(SystemExit, match="no checkpoint found"):
         _run_cli(monkeypatch, "cost-model",

@@ -1,19 +1,20 @@
 """Mesh train step (DESIGN.md §13): GlobalBatchSampler stacking, dp=1
-bit-parity with the legacy jit path, dp>=2 data parallelism, compress
-composition, and cross-layout checkpoint restore.
+bit-parity with a plain value_and_grad + AdamW reference, dp>=2 data
+parallelism, compress composition, and cross-layout checkpoint restore.
 
 Tests needing two devices skip on a single-device host; CI runs this file
 once under XLA_FLAGS=--xla_force_host_platform_device_count=2 (the
 tier-1 mesh-parity step) so they execute there, and
-benchmarks/bench_scaling.py gates the same properties end-to-end in
-subprocesses with forced device counts.
+benchmarks/bench_scaling.py gates scaling and checkpoint elasticity
+end-to-end in subprocesses with forced device counts.
 """
 import jax
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.core.model import CostModelConfig
+from repro.core.losses import pairwise_rank_loss
+from repro.core.model import CostModelConfig, cost_model_apply, cost_model_init
 from repro.core.simulator import TPUSimulator
 from repro.data.sampler import (
     BalancedSampler,
@@ -24,6 +25,7 @@ from repro.data.synthetic import generate_program, random_kernel
 from repro.data.tile_dataset import build_tile_records, fit_tile_normalizer
 from repro.sharding.context import shard_map_nocheck
 from repro.sharding.mesh import DATA_AXIS, make_train_mesh
+from repro.training.optim import adamw_init, adamw_update
 from repro.training.trainer import CostModelTrainer, TrainerConfig, step_keys
 
 needs_two = pytest.mark.skipif(
@@ -79,16 +81,19 @@ def test_global_batch_stacks_with_device_axis(tile_records, norm):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+@pytest.mark.parametrize("adjacency", ["dense", "sparse", "segmented"])
 def test_global_batch_dp1_is_base_stream_with_leading_axis(tile_records,
-                                                           norm):
-    s = _sampler(tile_records, norm)
-    g = GlobalBatchSampler.for_mesh(_sampler(tile_records, norm), 1)
+                                                           norm, adjacency):
+    s = _sampler(tile_records, norm, adjacency)
+    g = GlobalBatchSampler.for_mesh(_sampler(tile_records, norm, adjacency),
+                                    1)
     for step in (0, 3):
         a, b = s.batch(step), g.batch(step)
         np.testing.assert_array_equal(a.targets, b.targets[0])
         np.testing.assert_array_equal(a.valid, b.valid[0])
+        assert type(a.graphs) is type(b.graphs)
         for x, y in zip(jax.tree_util.tree_leaves(a.graphs),
-                        jax.tree_util.tree_leaves(b.graphs)):
+                        jax.tree_util.tree_leaves(b.graphs), strict=True):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y)[0])
 
 
@@ -148,20 +153,16 @@ def test_balanced_sampler_shards_too(tile_records, norm):
 
 # ----------------------------------------------------------- validation
 def test_trainer_rejects_segmented_under_mesh(tile_records, norm):
-    with pytest.raises(ValueError, match="segmented"):
-        _trainer(tile_records, norm, dp=1, adjacency="segmented")
+    """Segmented training runs at dp=1 only; dp=2 is refused before the
+    mesh is built, so a one-device host raises the same error."""
+    with pytest.raises(ValueError, match="segmented.*dp=1"):
+        _trainer(tile_records, norm, dp=2, adjacency="segmented")
 
 
-def test_trainer_compress_sparse_error_names_both_flags(tile_records, norm):
-    with pytest.raises(ValueError) as e:
-        _trainer(tile_records, norm, dp=0, compress_grads=True)
-    msg = str(e.value)
-    assert "compress_grads" in msg and "dp" in msg
-
-
-def test_trainer_rejects_wrong_data_axis(tile_records, norm):
-    with pytest.raises(ValueError, match="data_axis"):
-        _trainer(tile_records, norm, dp=1, data_axis="batch")
+@pytest.mark.parametrize("dp", [0, -1])
+def test_trainer_rejects_dp_below_one(tile_records, norm, dp):
+    with pytest.raises(ValueError, match="dp must be >= 1"):
+        _trainer(tile_records, norm, dp=dp)
 
 
 def test_make_train_mesh_errors_name_the_fix():
@@ -179,35 +180,49 @@ def test_trainer_rejects_mismatched_global_sampler(tile_records, norm):
 
 
 # ------------------------------------------------------------ bit-parity
-def test_dp1_mesh_step_bit_identical_to_legacy(tile_records, norm):
-    """The tentpole invariant: TrainerConfig(dp=1) reproduces the legacy
-    jit path exactly — same loss float, byte-identical params."""
-    t0 = _trainer(tile_records, norm, dp=0)
-    r0 = t0.run(resume=False)
-    t1 = _trainer(tile_records, norm, dp=1)
-    r1 = t1.run(resume=False)
-    assert r0["loss"] == r1["loss"]
-    for a, b in zip(_leaves(t0.params), _leaves(t1.params)):
-        np.testing.assert_array_equal(a, b)
-    for a, b in zip(_leaves(t0.opt_state), _leaves(t1.opt_state)):
+@pytest.mark.parametrize("adjacency", ["dense", "sparse", "segmented"])
+def test_dp1_step_matches_plain_reference(tile_records, norm, adjacency):
+    """TrainerConfig(dp=1) trains exactly as a plain jitted value_and_grad
+    + AdamW step on the base sampler's batches with device 0's dropout
+    key: same loss float, byte-identical params, after 3 steps."""
+    t = _trainer(tile_records, norm, dp=1, adjacency=adjacency)
+    mcfg, cfg = t.model_cfg, t.cfg
+    res = t.run(resume=False)
+
+    @jax.jit
+    def ref_step(params, opt, graphs, targets, groups, valid, base, step):
+        def loss_fn(p):
+            preds = cost_model_apply(p, mcfg, graphs,
+                                     rng=step_keys(base, step, 1)[0],
+                                     deterministic=False)
+            return pairwise_rank_loss(preds, targets, groups, valid)
+        loss, grads = jax.value_and_grad(loss_fn)(params)
+        params, opt, _ = adamw_update(params, grads, opt, cfg.optim)
+        return params, opt, loss
+
+    params = cost_model_init(jax.random.key(cfg.seed), mcfg)
+    opt = adamw_init(params)
+    base = _sampler(tile_records, norm, adjacency)
+    for step in range(cfg.steps):
+        b = base.batch(step)
+        params, opt, loss = ref_step(params, opt, b.graphs, b.targets,
+                                     b.group_ids, b.valid,
+                                     jax.random.key(cfg.seed + 1),
+                                     np.int32(step))
+    assert res["loss"] == float(loss)
+    for a, b in zip(_leaves(params), _leaves(t.params), strict=True):
         np.testing.assert_array_equal(a, b)
 
 
 # ------------------------------------------------------------ step keys
 @pytest.mark.parametrize("step", [0, 1, 37, 2**20])
-@pytest.mark.parametrize("dp", [0, 1, pytest.param(2, marks=needs_two)])
+@pytest.mark.parametrize("dp", [1, pytest.param(2, marks=needs_two)])
 def test_step_keys_in_jit_match_host_ladder(dp, step):
     """The keys the jitted step folds from the step number are the host
     ladder fold_in(key(seed + 1), step*dp + d) of DESIGN.md §13, byte for
     byte, and under the mesh device d holds key d."""
     base = jax.random.key(2**31 + 8)      # key(seed + 1), seed 2**31 + 7
     data = jax.random.key_data
-    if dp == 0:
-        got = jax.jit(step_keys)(base, np.int32(step))
-        np.testing.assert_array_equal(
-            np.asarray(data(got)),
-            np.asarray(data(jax.random.fold_in(base, step))))
-        return
     per_device = shard_map_nocheck(data, make_train_mesh(dp),
                                    in_specs=P(DATA_AXIS),
                                    out_specs=P(DATA_AXIS))
@@ -218,11 +233,11 @@ def test_step_keys_in_jit_match_host_ladder(dp, step):
     np.testing.assert_array_equal(np.asarray(got), np.stack(want))
 
 
-@pytest.mark.parametrize("dp", [0, 1])
-def test_step_number_adds_no_compile(tile_records, norm, dp):
+@pytest.mark.parametrize("adjacency", ["sparse", "segmented"])
+def test_step_number_adds_no_compile(tile_records, norm, adjacency):
     """The step number is a traced argument of the train step: after a
     warm-up run, eight more steps of the same bucket compile nothing."""
-    t = _trainer(tile_records, norm, dp=dp)
+    t = _trainer(tile_records, norm, dp=1, adjacency=adjacency)
     sampler = t.sampler
 
     class Replay:
@@ -246,14 +261,15 @@ def test_step_number_adds_no_compile(tile_records, norm, dp):
     assert compiles == []
 
 
-@pytest.mark.parametrize("dp", [0, 1])
+@pytest.mark.parametrize("adjacency", ["sparse", "dense"])
 def test_train_step_program_is_the_same_for_every_seed(tile_records, norm,
-                                                       dp):
+                                                       adjacency):
     """The seed reaches the train step as an argument, not as a constant
     of its trace: trainers of two seeds lower the same program, so one
     persistent-cache entry serves every seed."""
     def program(seed):
-        t = _trainer(tile_records, norm, dp=dp, seed=seed)
+        t = _trainer(tile_records, norm, dp=1, adjacency=adjacency,
+                     seed=seed)
         b = t.sampler.batch(0)
         return t._train_step.lower(
             t.params, t.opt_state, b.graphs, b.targets, b.group_ids,
